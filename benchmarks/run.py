@@ -64,6 +64,9 @@ def main() -> None:
         if unknown:  # a typo'd --only must not report 0/0 OK in CI
             ap.error(f"unknown benchmark module(s): {', '.join(unknown)}")
     todo = [m for m in MODULES if not args.only or m in args.only.split(",")]
+    from repro.backends import use_checkout_compile_cache
+
+    use_checkout_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     entries: list[tuple[str, str, float]] = []
     t_all = time.time()
     for name in todo:

@@ -66,6 +66,23 @@ def default_mesh_devices() -> int:
     return n
 
 
+def use_checkout_compile_cache(checkout) -> str:
+    """Keep JAX's persistent compilation cache at a fixed place; → its dir.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, governs (JAX reads it itself)
+    and no other directory is set.  Otherwise the cache lives at
+    ``<checkout>/.jax_cache`` — a fixed path, since the directory is part
+    of what a later run must find again.  The kernels compile in well
+    under a second, so the minimum compile time worth caching is zero.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if not path:
+        path = os.path.join(os.fspath(checkout), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
 def kernels_use_ref(use_ref: bool | None = None) -> bool:
     """Whether the device backend should run the jnp kernel oracles.
 

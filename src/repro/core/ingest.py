@@ -94,19 +94,26 @@ def _per_partition(plane, core, arrays, num_partitions, **static) -> np.ndarray:
 
 
 def measures_from_moments(raw: np.ndarray, rows: int, positive: bool) -> np.ndarray:
-    """Map kernel moments (P, 8) → paper measure layout (P, 9).
+    """Map kernel moments (P, 10) → paper measure layout (P, 9).
 
     Layout (sketches.MEASURE_NAMES): mean, min, max, meansq, std,
-    logmean, logmeansq, logmin, logmax.
+    logmean, logmeansq, logmin, logmax.  The kernel's power sums are
+    shifted (``raw[:, 2:4]`` = Σ(x−s), Σ(x−s)², ``raw[:, 8]`` = s), so
+    mean and meansq are re-based and std is taken from the centered
+    moments — in float64, without the cancellation of Σx²/n − mean².
     """
     p = raw.shape[0]
     out = np.zeros((p, 9), np.float64)
-    mn, mx, s, ss, lmn, lmx, ls, lss = [raw[:, i].astype(np.float64) for i in range(8)]
-    out[:, 0] = s / rows
+    mn, mx, s1, s2, lmn, lmx, ls, lss, shift = [
+        raw[:, i].astype(np.float64) for i in range(9)
+    ]
+    m1 = s1 / rows  # mean of x − shift
+    m2 = s2 / rows
+    out[:, 0] = shift + m1
     out[:, 1] = mn
     out[:, 2] = mx
-    out[:, 3] = ss / rows
-    out[:, 4] = np.sqrt(np.maximum(ss / rows - (s / rows) ** 2, 0.0))
+    out[:, 3] = shift * shift + 2.0 * shift * m1 + m2
+    out[:, 4] = np.sqrt(np.maximum(m2 - m1 * m1, 0.0))
     if positive:
         out[:, 5] = ls / rows
         out[:, 6] = lss / rows
@@ -203,10 +210,10 @@ def merge_discrete_span(
 # mergeable-statistic primitives (streaming ingest)
 # --------------------------------------------------------------------------
 # Raw kernel-moment layout (see `_moments_core` / `measures_from_moments`):
-# [min, max, sum, sumsq, logmin, logmax, logsum, logsumsq].  Sums add,
-# extrema combine by min/max — so two row-chunks of the same partitions
-# merge in O(P) regardless of chunk size, and the count weighting falls
-# out of `measures_from_moments(merged, rows_a + rows_b)`.
+# [min, max, Σ(x−s), Σ(x−s)², logmin, logmax, logsum, logsumsq, s, rows].
+# Extrema combine by min/max, the log sums and row counts add, and the
+# shifted power sums re-center onto the first chunk's shift — so two
+# row-chunks of the same partitions merge in O(P) regardless of chunk size.
 #
 # The row-chunk merge primitives (`merge_moments`, `merge_bincounts`, the
 # AKMV trio in `core/sketches.py`) are the mergeable-summary foundation;
@@ -215,26 +222,31 @@ def merge_discrete_span(
 # realignment — the row-chunk forms are held correct by
 # `tests/test_streaming_ingest.py` as the paper-level mergeability
 # property and as oracles for any future sub-partition streaming.
-_MOMENT_MERGE = ("min", "max", "add", "add", "min", "max", "add", "add")
+_MOMENT_MERGE = ("min", "max", "shifted", "shifted", "min", "max", "add", "add",
+                 "keep", "add")
 
 
 def merge_moments(a: np.ndarray, b: np.ndarray) -> np.ndarray:
-    """Merge raw (P, 8) kernel moments of two row-chunks of the same
+    """Merge raw (P, 10) kernel moments of two row-chunks of the same
     partitions.  Exact for min/max and integer-valued sums; float sums are
     re-associated (chunk partials added instead of one long fold), so a
     merged result matches the one-shot kernel to f32 rounding, not
-    bitwise.  The streaming *partition-append* path never calls this on
+    bitwise.  ``b``'s shifted sums move onto ``a``'s shift in float64
+    (Σ(x−sa) = Σ(x−sb) + n·δ, Σ(x−sa)² = Σ(x−sb)² + 2δ·Σ(x−sb) + n·δ²).
+    The streaming *partition-append* path never calls this on
     overlapping partitions — appended partitions fold their rows in one
     pass, which is how the append plane stays bit-identical to a cold
     rebuild."""
-    out = np.empty_like(a)
-    for i, how in enumerate(_MOMENT_MERGE):
-        if how == "add":
-            out[:, i] = a[:, i] + b[:, i]
-        elif how == "min":
-            out[:, i] = np.minimum(a[:, i], b[:, i])
-        else:
-            out[:, i] = np.maximum(a[:, i], b[:, i])
+    a = a.astype(np.float64)
+    b = b.astype(np.float64)
+    how = np.asarray(_MOMENT_MERGE)
+    out = np.where(how == "min", np.minimum(a, b),
+                   np.where(how == "max", np.maximum(a, b),
+                            np.where(how == "keep", a, a + b)))
+    delta = b[:, 8] - a[:, 8]
+    nb = b[:, 9]
+    out[:, 2] += nb * delta
+    out[:, 3] += 2.0 * delta * b[:, 2] + nb * delta * delta
     return out
 
 

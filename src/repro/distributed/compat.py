@@ -1,11 +1,9 @@
-"""JAX version-compat shims for the distribution layer.
+"""Mesh and cost-analysis helpers for the distribution layer.
 
-`jax.make_mesh` gained the `axis_types` kwarg (and `jax.sharding.AxisType`)
-only in newer JAX releases; the pinned toolchain here (0.4.x) predates both.
-Every mesh in the repo is built through `make_mesh` below so the axis-type
-request degrades gracefully: when the running JAX understands explicit axis
-types we pass them through, otherwise we build the plain mesh (0.4.x meshes
-are implicitly Auto on every axis, which is exactly what we ask for).
+Every mesh in the repo is built through `make_mesh` so all axes are
+explicitly `AxisType.Auto` (sharding propagated by the compiler), and
+`cost_analysis_dict` gives callers a plain dict even when XLA reports
+nothing.
 """
 from __future__ import annotations
 
@@ -14,33 +12,14 @@ from typing import Sequence
 import jax
 
 
-def _auto_axis_types(n: int):
-    """(AxisType.Auto,) * n on JAX versions that have it, else None."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n
-
-
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
-    """Build a mesh with Auto axis types on any supported JAX version."""
-    shape, axes = tuple(shape), tuple(axes)
-    types = _auto_axis_types(len(axes))
-    if types is not None:
-        try:
-            return jax.make_mesh(shape, axes, axis_types=types)
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+    """Build a mesh with Auto axis types."""
+    axes = tuple(axes)
+    return jax.make_mesh(
+        tuple(shape), axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Normalize Compiled.cost_analysis() across JAX versions.
-
-    0.4.x returns a list with one dict per executable program; newer
-    versions return the dict directly (or None when XLA provides nothing).
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    return ca or {}
+    """``Compiled.cost_analysis()`` as a dict ({} when XLA provides nothing)."""
+    return compiled.cost_analysis() or {}

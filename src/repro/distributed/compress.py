@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 GROUP = 128
@@ -73,12 +72,12 @@ def compressed_pod_mean(grads, mesh, errors):
     # grads are already sharded; shard_map over pod with everything else
     # replicated across "pod" (each pod holds its own replica's grads).
     specs = jax.tree.map(lambda _: P(), grads)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=((specs, specs),),
         out_specs=(specs, specs),
-        check_rep=False,
+        check_vma=False,
     )
     return fn((grads, errors))
 

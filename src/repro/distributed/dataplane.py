@@ -39,7 +39,6 @@ import functools
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.distributed.axes import PARTITION_AXIS
@@ -143,11 +142,11 @@ def resolve_plane(plane="auto") -> PartitionPlane | None:
 def _sharded_jit(mesh, fn, in_specs, out_specs, static):
     body = functools.partial(fn, **dict(static)) if static else fn
     # bodies are purely shard-local (no collectives) and outputs declare
-    # their partitioned axes explicitly, so replication checking buys
-    # nothing and trips over primitives without rep rules (segment_sum)
+    # their partitioned axes explicitly, so varying-manual-axes checking
+    # buys nothing and trips over primitives without rules (segment_sum)
     return jax.jit(
-        shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+        jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                      check_vma=False)
     )
 
 

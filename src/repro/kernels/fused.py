@@ -3,10 +3,10 @@
 `predicate.py` and `groupagg.py` run the executor hot loop as two
 launches with a (B, R) row-mask tensor round-tripping through HBM between
 them.  This kernel fuses both: each row tile evaluates the AND-of-ORs
-interval predicate in VMEM (the `predicate.py` max/min contraction), folds
-the resulting mask straight into the group codes, and contracts the tile
-one-hot against the aggregate components on the MXU (the `groupagg.py`
-trick) — the mask never exists outside the tile.
+interval predicate in VMEM (`predicate.tile_mask`), folds the resulting
+mask straight into the group codes, and contracts the tile one-hot
+against the aggregate components on the MXU (the `groupagg.py` trick) —
+the mask never exists outside the tile.
 
 Grid: (batch, group_tiles, row_tiles); row tiles accumulate into the same
 (V, bg) output block (sequential revisiting).
@@ -19,37 +19,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import LANE, SUBLANE, interpret, pick_block, round_up
+from repro.kernels.common import (
+    LANE, SUBLANE, contract_rows, interpret, pick_block, round_up,
+)
+from repro.kernels.predicate import canonical_operands, tile_mask
 
 
 def _kernel(x_ref, lo_ref, hi_ref, gmap_ref, vals_ref, codes_ref, o_ref, *, bg: int):
-    x = x_ref[...].astype(jnp.float32)  # (1, C, bt)
-    lo = lo_ref[...]  # (1, C)
-    hi = hi_ref[...]
-    gm = gmap_ref[...][0]  # (C, G)
-    v = vals_ref[...].astype(jnp.float32)  # (1, V, bt)
-    c = codes_ref[...][0]  # (bt,) int32, -1 = padding
-
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    # predicate: clause intervals → OR within groups (max) → AND across (min)
-    clause = (x[0] >= lo[0][:, None]) & (x[0] < hi[0][:, None])  # (C, bt)
-    cf = clause.astype(jnp.float32)
-    grouped = jnp.max(
-        jnp.where(gm.T[:, :, None] > 0, cf[None, :, :], 0.0), axis=1
-    )  # (G, bt)
-    mask = jnp.min(grouped, axis=0)  # (bt,)
-
+    mask = tile_mask(x_ref[0], lo_ref[0], hi_ref[0], gmap_ref[0])  # (1, bt)
+    c = codes_ref[0]  # (1, bt) int32, -1 = padding
     # aggregate: fold the mask into the codes, contract the tile one-hot
     mcodes = jnp.where((mask > 0.5) & (c >= 0), c, -1)
-    gbase = pl.program_id(1) * bg
-    bins = gbase + jax.lax.broadcasted_iota(jnp.int32, (1, bg), 1)
-    onehot = (mcodes[:, None] == bins).astype(jnp.float32)  # (bt, bg)
-    o_ref[0] += jax.lax.dot_general(
-        v[0], onehot, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    bins = pl.program_id(1) * bg + jax.lax.broadcasted_iota(jnp.int32, (bg, 1), 0)
+    o_ref[0] += contract_rows(vals_ref[0], mcodes == bins)  # (bg, bt) one-hot
 
 
 @functools.partial(
@@ -75,26 +61,23 @@ def fused_eval(
     vp = round_up(v, SUBLANE)
     bg = pick_block(num_groups, block_groups, LANE)
     gp = round_up(num_groups, bg)
-    # pad clause rows with NaN: fails every interval test => mask 0
-    xp = jnp.pad(cols.astype(jnp.float32), ((0, 0), (0, 0), (0, rp - r)),
-                 constant_values=jnp.nan)
+    xp, lo, hi, gmap_t = canonical_operands(cols, lo, hi, group_map, rp)
     vals = jnp.pad(values.astype(jnp.float32), ((0, 0), (0, vp - v), (0, rp - r)))
     cp = jnp.pad(codes.astype(jnp.int32), ((0, 0), (0, rp - r)),
-                 constant_values=-1)
+                 constant_values=-1)[:, None, :]
     out = pl.pallas_call(
         functools.partial(_kernel, bg=bg),
         grid=(b, gp // bg, rp // bt),
         in_specs=[
             pl.BlockSpec((1, c, bt), lambda i, j, l: (i, 0, l)),
-            pl.BlockSpec((1, c), lambda i, j, l: (i, 0)),
-            pl.BlockSpec((1, c), lambda i, j, l: (i, 0)),
-            pl.BlockSpec((1, c, g), lambda i, j, l: (i, 0, 0)),
+            pl.BlockSpec((1, c, 1), lambda i, j, l: (i, 0, 0)),
+            pl.BlockSpec((1, c, 1), lambda i, j, l: (i, 0, 0)),
+            pl.BlockSpec((1, g, c), lambda i, j, l: (i, 0, 0)),
             pl.BlockSpec((1, vp, bt), lambda i, j, l: (i, 0, l)),
-            pl.BlockSpec((1, bt), lambda i, j, l: (i, l)),
+            pl.BlockSpec((1, 1, bt), lambda i, j, l: (i, 0, l)),
         ],
         out_specs=pl.BlockSpec((1, vp, bg), lambda i, j, l: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, vp, gp), jnp.float32),
         interpret=interpret(),
-    )(xp, lo.astype(jnp.float32), hi.astype(jnp.float32),
-      group_map.astype(jnp.float32), vals, cp)
+    )(xp, lo, hi, gmap_t, vals, cp)
     return out[:, :v, :num_groups]

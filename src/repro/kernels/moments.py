@@ -7,14 +7,27 @@ instead of the four separate passes a sketch-per-pass implementation would
 make.  The kernel is memory-bound by construction (8 flops/elem vs 4 bytes
 read), so fusing the passes is the whole optimization.
 
-Grid: (partitions, row_tiles).  The row-tile axis accumulates into the
-(1, 8)-shaped output block using the sequential-grid revisiting pattern
-(output block index is independent of the reduced axis), which avoids
-scratch and works identically under interpret mode.
+The power sums are taken about a per-partition shift ``s`` (the
+partition's first value, 0 if non-finite): Σ(x−s) and Σ(x−s)².  Raw f32
+Σx² cannot carry a variance that is small next to the mean² — a date
+column's partition on a date-sorted layout spans a few days around
+~1,000, so Σx² ≈ 1.6e10 at 16,384 rows, whose f32 ulp swamps the
+variance — while the shifted sums keep it.  The shift and the row count
+ride along in the output so the host reconstructs mean / meansq / std in
+float64 (`core.ingest.measures_from_moments`) and row-chunk partials
+re-center exactly (`core.ingest.merge_moments`).
 
-Rows are padded to the lane width with neutral elements (+inf/-inf/0) by
-the ops wrapper; log statistics use max(x, tiny) exactly like the host
-reference so allclose tests are exact-modulo-float.
+Grid: (partition_tiles, row_tiles).  Each step reads an (8, bt) tile —
+eight partitions on the sublanes, rows on the lanes — and reduces every
+statistic along the lanes, so each partition's fold stays row-local.  The
+eight statistics sit in lanes 0..7 of a lane-dense (8, 128) output block
+that the row-tile axis accumulates into (sequential revisiting: the output
+block index is independent of the reduced axis), combined per lane by
+min, max or add.
+
+Row validity comes from the lane index (rows past R are padding), so no
+mask array is streamed; log statistics use max(x, tiny) exactly like the
+host reference so allclose tests are exact-modulo-float.
 """
 from __future__ import annotations
 
@@ -24,39 +37,60 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import LANE, interpret, pick_block, round_up
+from repro.kernels.common import LANE, SUBLANE, interpret, pick_block, round_up
 
-NSTATS = 8  # min, max, sum, sumsq, logmin, logmax, logsum, logsumsq
+# min, max, Σ(x−s), Σ(x−s)², logmin, logmax, logsum, logsumsq, shift s, rows
+NSTATS = 10
+SHIFT = 8
 _TINY = 1e-30
+_MIN_LANES = (0, 4)
+_MAX_LANES = (1, 5)
 
 
-def _kernel(x_ref, valid_ref, o_ref):
-    x = x_ref[...].astype(jnp.float32)  # (1, bt)
-    v = valid_ref[...]  # (1, bt) 1/0 row-validity mask
+def _lanes_in(lane, which):
+    return functools.reduce(jnp.logical_or, [lane == k for k in which])
 
-    @pl.when(pl.program_id(1) == 0)
+
+def _kernel(x_ref, o_ref, *, rows: int, bt: int):
+    j = pl.program_id(1)
+    x = x_ref[...].astype(jnp.float32)  # (8, bt)
+    lane = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
+    is_min = _lanes_in(lane, _MIN_LANES)
+    is_max = _lanes_in(lane, _MAX_LANES)
+
+    @pl.when(j == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-        o_ref[0, 0] = jnp.inf  # min
-        o_ref[0, 1] = -jnp.inf  # max
-        o_ref[0, 4] = jnp.inf  # logmin
-        o_ref[0, 5] = -jnp.inf  # logmax
+        x0 = x[:, 0:1]
+        shift = jnp.where(jnp.isfinite(x0), x0, 0.0)
+        o_ref[...] = jnp.where(
+            is_min, jnp.inf,
+            jnp.where(is_max, -jnp.inf, jnp.where(lane == SHIFT, shift, 0.0)),
+        )
 
-    big = jnp.where(v > 0, x, jnp.inf)
-    small = jnp.where(v > 0, x, -jnp.inf)
+    acc = o_ref[...]
+    col = j * bt + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    v = col < rows
     lx = jnp.log(jnp.maximum(x, _TINY))
-    lbig = jnp.where(v > 0, lx, jnp.inf)
-    lsmall = jnp.where(v > 0, lx, -jnp.inf)
-    xm = x * v
-    lm = lx * v
-    o_ref[0, 0] = jnp.minimum(o_ref[0, 0], jnp.min(big))
-    o_ref[0, 1] = jnp.maximum(o_ref[0, 1], jnp.max(small))
-    o_ref[0, 2] += jnp.sum(xm)
-    o_ref[0, 3] += jnp.sum(xm * x)
-    o_ref[0, 4] = jnp.minimum(o_ref[0, 4], jnp.min(lbig))
-    o_ref[0, 5] = jnp.maximum(o_ref[0, 5], jnp.max(lsmall))
-    o_ref[0, 6] += jnp.sum(lm)
-    o_ref[0, 7] += jnp.sum(lm * lx)
+    d = jnp.where(v, x - acc[:, SHIFT : SHIFT + 1], 0.0)
+    lm = jnp.where(v, lx, 0.0)
+    stats = (
+        jnp.min(jnp.where(v, x, jnp.inf), axis=1, keepdims=True),
+        jnp.max(jnp.where(v, x, -jnp.inf), axis=1, keepdims=True),
+        jnp.sum(d, axis=1, keepdims=True),
+        jnp.sum(d * d, axis=1, keepdims=True),
+        jnp.min(jnp.where(v, lx, jnp.inf), axis=1, keepdims=True),
+        jnp.max(jnp.where(v, lx, -jnp.inf), axis=1, keepdims=True),
+        jnp.sum(lm, axis=1, keepdims=True),
+        jnp.sum(lm * lx, axis=1, keepdims=True),
+    )  # each (8, 1); the shift lane adds 0, the rows lane counts
+    tile = jnp.where(lane == NSTATS - 1, jnp.sum(v.astype(jnp.float32), axis=1,
+                                                 keepdims=True), 0.0)
+    for k, s in enumerate(stats):
+        tile = jnp.where(lane == k, s, tile)
+    o_ref[...] = jnp.where(
+        is_min, jnp.minimum(acc, tile),
+        jnp.where(is_max, jnp.maximum(acc, tile), acc + tile),
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows",))
@@ -65,18 +99,14 @@ def moments(x: jax.Array, block_rows: int = 2048) -> jax.Array:
     p, r = x.shape
     bt = pick_block(r, block_rows, LANE)
     rp = round_up(r, bt)
-    pad = rp - r
-    xp = jnp.pad(x, ((0, 0), (0, pad)))
-    valid = jnp.pad(jnp.ones((p, r), jnp.float32), ((0, 0), (0, pad)))
-    grid = (p, rp // bt)
-    return pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bt), lambda i, j: (i, j)),
-            pl.BlockSpec((1, bt), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, NSTATS), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((p, NSTATS), jnp.float32),
+    pp = round_up(p, SUBLANE)
+    xp = jnp.pad(x, ((0, pp - p), (0, rp - r)))
+    out = pl.pallas_call(
+        functools.partial(_kernel, rows=r, bt=bt),
+        grid=(pp // SUBLANE, rp // bt),
+        in_specs=[pl.BlockSpec((SUBLANE, bt), lambda i, j: (i, j))],
+        out_specs=pl.BlockSpec((SUBLANE, LANE), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((pp, LANE), jnp.float32),
         interpret=interpret(),
-    )(xp, valid)
+    )(xp)
+    return out[:p, :NSTATS]

@@ -6,9 +6,10 @@ stack (columns used by several clauses are duplicated — C ≤ 10 in the
 paper's clustering scope) and canonicalizes every clause to a half-open
 interval test  lo ≤ x < hi  (equality on coded categoricals becomes
 [v, v+1); negation flips to the complement pair handled by two clauses at
-IR level).  In-kernel, clause results are OR-combined within groups via a
-max contraction against the (C, G) group one-hot and AND-combined across
-groups via a min reduction — branch-free, VPU-only, one pass.
+IR level).  In-kernel, clause results are OR-combined within groups by a
+max over member clauses (an unrolled loop over the C clause rows against
+the (G, C) clause→group map) and AND-combined across groups by a min over
+the group rows — branch-free, VPU-only, one pass, rows on the lanes.
 
 Outputs both the row mask and the per-partition passing count (the
 selectivity ground truth used for picker training labels).
@@ -24,29 +25,35 @@ from jax.experimental import pallas as pl
 from repro.kernels.common import LANE, interpret, pick_block, round_up
 
 
-def _kernel(x_ref, lo_ref, hi_ref, gmap_ref, o_ref, cnt_ref, *, num_groups: int):
-    x = x_ref[...].astype(jnp.float32)  # (1, C, bt)
-    lo = lo_ref[...]  # (1, C)
-    hi = hi_ref[...]
-    gmap = gmap_ref[...]  # (1, C, G) one-hot clause→group map
+def tile_mask(x, lo, hi, gmap_t):
+    """(C, bt) columns, (C, 1) bounds, (G, C) group map → (1, bt) 0/1 mask.
 
-    clause = (x[0] >= lo[0][:, None]) & (x[0] < hi[0][:, None])  # (C, bt)
-    cf = clause.astype(jnp.float32)
-    # OR within groups: max over member clauses = contraction with one-hot
-    # (values are 0/1 so max == min(1, sum) on disjoint clause maps;
-    # we use the max formulation for exactness with overlapping maps)
-    gm = gmap[0]  # (C, G)
-    grouped = jnp.max(
-        jnp.where(gm.T[:, :, None] > 0, cf[None, :, :], 0.0), axis=1
-    )  # (G, bt)
-    mask = jnp.min(grouped, axis=0)  # AND across groups
+    OR within groups is a max over member clauses (exact for overlapping
+    maps too); AND across groups is a min over the group rows."""
+    cf = ((x >= lo) & (x < hi)).astype(jnp.float32)  # (C, bt)
+    grouped = jnp.zeros((gmap_t.shape[0], x.shape[1]), jnp.float32)
+    for k in range(x.shape[0]):
+        grouped = jnp.maximum(grouped, gmap_t[:, k : k + 1] * cf[k : k + 1, :])
+    return jnp.min(grouped, axis=0, keepdims=True)
 
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    o_ref[0] = mask
-    cnt_ref[0, 0] += jnp.sum(mask)
+def _kernel(x_ref, lo_ref, hi_ref, gmap_ref, o_ref):
+    o_ref[0] = tile_mask(
+        x_ref[0].astype(jnp.float32), lo_ref[0], hi_ref[0], gmap_ref[0]
+    )
+
+
+def canonical_operands(cols, lo, hi, group_map, rp):
+    """Kernel operand layouts shared with `fused.fused_eval`: row-padded
+    columns (NaN pad fails every interval test ⇒ mask 0), (B, C, 1) bounds
+    and the (B, G, C) transposed clause→group map."""
+    b, c, r = cols.shape
+    xp = jnp.pad(cols.astype(jnp.float32), ((0, 0), (0, 0), (0, rp - r)),
+                 constant_values=jnp.nan)
+    lo = lo.astype(jnp.float32)[:, :, None]
+    hi = hi.astype(jnp.float32)[:, :, None]
+    gmap_t = jnp.swapaxes(group_map.astype(jnp.float32), 1, 2)
+    return xp, lo, hi, gmap_t
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups", "block_rows"))
@@ -65,34 +72,27 @@ def predicate_eval(
     query brings its own OR-group structure.
     """
     p, c, r = cols.shape
+    g = group_map.shape[-1]
     bt = pick_block(r, block_rows, LANE)
     rp = round_up(r, bt)
     if lo.ndim == 1:
         lo = jnp.broadcast_to(lo[None], (p, c))
         hi = jnp.broadcast_to(hi[None], (p, c))
-    # pad rows with NaN: fails every interval test => mask 0
-    xp = jnp.pad(cols.astype(jnp.float32), ((0, 0), (0, 0), (0, rp - r)),
-                 constant_values=jnp.nan)
-    gm = group_map.astype(jnp.float32)
-    if gm.ndim == 2:
-        gm = jnp.broadcast_to(gm[None], (p, c, num_groups))
-    mask, cnt = pl.pallas_call(
-        functools.partial(_kernel, num_groups=num_groups),
+    if group_map.ndim == 2:
+        group_map = jnp.broadcast_to(group_map[None], (p, c, num_groups))
+    xp, lo, hi, gmap_t = canonical_operands(cols, lo, hi, group_map, rp)
+    mask = pl.pallas_call(
+        _kernel,
         grid=(p, rp // bt),
         in_specs=[
             pl.BlockSpec((1, c, bt), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, c), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, c), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, c, num_groups), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, c, 1), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, c, 1), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, g, c), lambda i, j: (i, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, bt), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((p, rp), jnp.float32),
-            jax.ShapeDtypeStruct((p, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, 1, bt), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((p, 1, rp), jnp.float32),
         interpret=interpret(),
-    )(xp, lo, hi, gm)
-    return mask[:, :r], cnt[:, 0]
+    )(xp, lo, hi, gmap_t)
+    mask = mask[:, 0, :r]
+    return mask, jnp.sum(mask, axis=1)

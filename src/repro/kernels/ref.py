@@ -8,19 +8,25 @@ _TINY = 1e-30
 
 
 def moments_ref(x: jax.Array) -> jax.Array:
-    """(P, R) → (P, 8): min,max,sum,sumsq,logmin,logmax,logsum,logsumsq."""
+    """(P, R) → (P, 10): min, max, Σ(x−s), Σ(x−s)², logmin, logmax,
+    logsum, logsumsq, s, rows — s is each partition's first value (0 if
+    non-finite), as in `kernels/moments.py`."""
     x = x.astype(jnp.float32)
     lx = jnp.log(jnp.maximum(x, _TINY))
+    shift = jnp.where(jnp.isfinite(x[:, 0]), x[:, 0], 0.0)
+    d = x - shift[:, None]
     return jnp.stack(
         [
             jnp.min(x, axis=1),
             jnp.max(x, axis=1),
-            jnp.sum(x, axis=1),
-            jnp.sum(x * x, axis=1),
+            jnp.sum(d, axis=1),
+            jnp.sum(d * d, axis=1),
             jnp.min(lx, axis=1),
             jnp.max(lx, axis=1),
             jnp.sum(lx, axis=1),
             jnp.sum(lx * lx, axis=1),
+            shift,
+            jnp.full(x.shape[:1], x.shape[1], jnp.float32),
         ],
         axis=1,
     )
@@ -82,6 +88,7 @@ def blocked_onehot_aggregate(
         onehot = (ct[:, :, None] == bins).astype(jnp.float32)  # (P, bt, G)
         upd = jax.lax.dot_general(
             vt, onehot, (((2,), (1,)), ((0,), (0,))),
+            precision=jax.lax.Precision.HIGHEST,  # TPU default rounds to bf16
             preferred_element_type=jnp.float32,
         )
         return acc + upd, None

@@ -4,7 +4,7 @@ The level-wise tree learner's hot loop scatters every (row, sampled
 feature) pair's gradient and hessian into a ``(nodes, features, bins)``
 histogram — on GPU an atomic scatter-add.  The TPU adaptation follows the
 `groupagg` pattern: per sampled feature column, a row tile builds a one-hot
-``(rows × node·bin-segments)`` matrix that the MXU contracts against a
+``(node·bin-segments × rows)`` matrix that the MXU contracts against a
 ``(g; h)`` two-row stack, so one launch produces both histograms for every
 node of the current level:
 
@@ -27,11 +27,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import LANE, SUBLANE, interpret, pick_block, round_up
+from repro.kernels.common import (
+    LANE, SUBLANE, contract_rows, interpret, pick_block, round_up,
+)
 
 
 def _kernel(codes_ref, node_ref, gh_ref, o_ref, *, bs: int, num_bins: int):
-    c = codes_ref[...]  # (1, bt) int32 bin codes for this feature column
+    c = codes_ref[0]  # (1, bt) int32 bin codes for this feature column
     nd = node_ref[...]  # (1, bt) int32 level-node index; -1 = dropped row
     gh = gh_ref[...]  # (8, bt) f32; row 0 = g, row 1 = h, rest zero
 
@@ -39,13 +41,9 @@ def _kernel(codes_ref, node_ref, gh_ref, o_ref, *, bs: int, num_bins: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    seg = nd[0] * num_bins + c[0]  # (bt,) segment = node·B + bin
-    sbase = pl.program_id(1) * bs
-    bins = sbase + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    onehot = ((seg[:, None] == bins) & (nd[0] >= 0)[:, None]).astype(jnp.float32)
-    o_ref[0] += jax.lax.dot_general(
-        gh, onehot, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    seg = jnp.where(nd >= 0, nd * num_bins + c, -1)  # (1, bt) node·B + bin
+    bins = pl.program_id(1) * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+    o_ref[0] += contract_rows(gh, seg == bins)  # (bs, bt) one-hot
 
 
 @functools.partial(
@@ -71,7 +69,7 @@ def tree_hist(
     rp = round_up(r, bt)
     bs = pick_block(s, block_segs, LANE)
     sp = round_up(s, bs)
-    codes_t = jnp.pad(codes.astype(jnp.int32).T, ((0, 0), (0, rp - r)))
+    codes_t = jnp.pad(codes.astype(jnp.int32).T, ((0, 0), (0, rp - r)))[:, None, :]
     node_p = jnp.pad(node.astype(jnp.int32)[None], ((0, 0), (0, rp - r)), constant_values=-1)
     gh = jnp.zeros((SUBLANE, rp), jnp.float32)
     gh = gh.at[0, :r].set(g.astype(jnp.float32)).at[1, :r].set(h.astype(jnp.float32))
@@ -79,7 +77,7 @@ def tree_hist(
         functools.partial(_kernel, bs=bs, num_bins=num_bins),
         grid=(c, sp // bs, rp // bt),
         in_specs=[
-            pl.BlockSpec((1, bt), lambda i, j, l: (i, l)),
+            pl.BlockSpec((1, 1, bt), lambda i, j, l: (i, 0, l)),
             pl.BlockSpec((1, bt), lambda i, j, l: (0, l)),
             pl.BlockSpec((SUBLANE, bt), lambda i, j, l: (0, l)),
         ],

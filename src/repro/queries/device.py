@@ -57,6 +57,7 @@ compile-bound test asserts the census, `bench_offline` reports it.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 
 import jax
@@ -72,6 +73,8 @@ from repro.queries import engine
 from repro.queries.ir import Aggregate, Predicate, Query
 
 TRACES = TraceRegistry("query_eval")
+# queries `eval_workload` sent to the host path instead ({describe(): n})
+HOST_FALLBACKS: collections.Counter = collections.Counter()
 
 # cap on stacked f32 elements per launch (Q_b · N · max(C_b, V_b) · R)
 MAX_STACK_ELEMS = 1 << 25
@@ -312,11 +315,14 @@ def _device_inputs(stack, col_idx, coefs, mults):
     # below read the raw stack, where non-finite rows compare exactly
     flat = stack.reshape(ncols1, p * r)
     flat = jnp.where(jnp.isfinite(flat), flat, jnp.float32(0))
-    # aggregate components: linear projections = coefficient matmul (MXU)
-    values = jnp.einsum("qvc,cs->qvs", coefs, flat).reshape(qb, vb, p, r)
-    values = values.transpose(0, 2, 1, 3).reshape(qb * p, vb, r)
+    # aggregate components: linear projections = coefficient matmul (MXU).
+    # HIGHEST: the TPU's default f32 matmul rounds operands to bf16, which
+    # loses sums past rtol 1e-5 and integer codes past 256
+    hi = jax.lax.Precision.HIGHEST
+    values = jnp.einsum("qvc,cs->qvs", coefs, flat, precision=hi)
+    values = values.reshape(qb, vb, p, r).transpose(0, 2, 1, 3).reshape(qb * p, vb, r)
     # mixed-radix group codes: integer-valued f32 matvec (exact below 2^24)
-    codes = jnp.einsum("qc,cs->qs", mults, flat).reshape(qb, p, r)
+    codes = jnp.einsum("qc,cs->qs", mults, flat, precision=hi).reshape(qb, p, r)
     codes = jnp.round(codes).astype(jnp.int32).reshape(qb * p, r)
     # clause columns: device gather instead of host stacking
     x = stack[col_idx]  # (Qb, Cb, P, R)
@@ -568,6 +574,7 @@ def eval_workload(
     grouped, fallback = _plan_workload(table, queries, cache)
     out: list[engine.PartitionAnswers | None] = [None] * len(queries)
     for i, q in fallback:  # inexpressible predicates: exact-parity host path
+        HOST_FALLBACKS[q.describe()] += 1
         out[i] = engine._host_answers(table, q, cache)
     if use_ref is None and cache.plane is None and jax.default_backend() != "tpu":
         for _sig, entries in grouped.items():

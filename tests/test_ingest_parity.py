@@ -99,3 +99,21 @@ def test_discrete_span():
     assert discrete_span(np.asarray([[1.0, 4.0, 2.0]])) == (1, 4)
     assert discrete_span(np.asarray([[1.5, 4.0]])) is None
     assert discrete_span(np.asarray([[0.0, 1e6]])) is None
+
+
+@pytest.mark.parametrize("use_ref", [True, False], ids=["xla-ref", "pallas"])
+def test_measures_keep_small_variance_at_large_mean(use_ref):
+    """A date-sorted partition: values ~1,000 with a spread of a few units
+    over 16,384 rows.  Raw f32 Σx² (≈1.6e10) cannot carry that variance;
+    the kernels' shifted power sums must."""
+    from repro.core.ingest import measures_from_moments
+    from repro.kernels import ops
+
+    rng = np.random.default_rng(7)
+    x = (1000.0 + np.sort(rng.integers(0, 3, size=(4, 16384)), axis=1)).astype(np.float32)
+    raw = np.asarray(ops.moments_op(x, use_ref=use_ref))
+    got = measures_from_moments(raw, x.shape[1], positive=True)
+    xd = x.astype(np.float64)
+    np.testing.assert_allclose(got[:, 0], xd.mean(1), rtol=1e-7)
+    np.testing.assert_allclose(got[:, 3], (xd * xd).mean(1), rtol=1e-7)
+    np.testing.assert_allclose(got[:, 4], xd.std(1), rtol=2e-4, atol=2e-4)

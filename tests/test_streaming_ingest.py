@@ -478,8 +478,9 @@ def test_merge_primitives_accept_empty_partition_batches():
     but one slot) flow through every merge primitive without special
     cases and produce shape-correct empty results."""
     empty = np.empty((0, 64))
-    m = ingest.merge_moments(np.empty((0, 8)), np.empty((0, 8)))
-    assert m.shape == (0, 8)
+    width = len(ingest._MOMENT_MERGE)
+    m = ingest.merge_moments(np.empty((0, width)), np.empty((0, width)))
+    assert m.shape == (0, width)
     merged, lo = ingest.merge_bincounts(
         np.zeros((0, 5)), np.zeros((0, 3)), lo_a=2, lo_b=0
     )
