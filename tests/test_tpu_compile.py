@@ -3,8 +3,8 @@
 The TPU compiler ships with jaxlib's TPU plugin and compiles for a chip
 that is described rather than attached, so these tests catch what
 interpret mode cannot: block shapes the Mosaic lowering refuses, layouts
-it cannot relayout, VMEM overruns, and driver programs that would not fit
-the chip's 16 GB of HBM.  Shapes are those of the one-chip smoke
+it cannot relayout, VMEM overruns, and query-eval programs that would
+not fit the chip's 16 GB of HBM.  Shapes are those of the one-chip smoke
 deployment (`chip_smoke.py`): 1,024 partitions × 16,384 rows of the
 TPC-H-like table.
 
@@ -17,7 +17,6 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -106,8 +105,8 @@ def test_kernel_compiles_for_v5e(native, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_eval_driver_fits_one_chip(native):
-    """The stacked query-eval driver over the full device column stack
+def test_stacked_eval_fits_one_chip(native):
+    """The stacked query-eval program over the full device column stack
     (Pallas fused kernel) compiles and fits the chip's HBM."""
     from repro.queries import device
 
@@ -121,5 +120,4 @@ def test_eval_driver_fits_one_chip(native):
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
-    assert used < HBM_BYTES, f"eval driver needs {used / 1e9:.2f} GB"
-    assert np.isfinite(used)
+    assert used < HBM_BYTES, f"stacked eval needs {used / 1e9:.2f} GB"
