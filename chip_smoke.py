@@ -14,16 +14,20 @@ One chip, one process, in order:
      ``Session.execute``, half through a started `FrontDoor` on the real
      clock;
   d. hold the chip to host numpy: the served queries' per-partition
-     answers (counts exact, sums rtol 1e-5) and the sketches (allclose).
+     answers (counts exact, sums rtol 1e-5), the sketches (allclose) and
+     the importance funnel's GBDT forests fit on those queries
+     (predictions allclose).
 
 With ``--chips 4`` it runs only the partition-mesh path — sketch
 statistics, per-partition answers and a few ``Session.execute`` calls
-under ``ExecOptions(mesh=4)`` — against the same work on one device.
+under ``ExecOptions(mesh=4)`` — against the same work on one device, and
+holds them bitwise equal.
 
 Each phase prints a line: wall seconds split into JAX trace/compile time
 and the rest, partitions read, whether each answer met its bound and how
 many of its confidence intervals covered the exact value, peak device
-bytes, and queries that took the host fallback.  The last line of stdout
+bytes, and queries that took the host fallback.  Coverage counts only
+answers whose exact result is non-empty; the empty ones are listed apart.  The last line of stdout
 is the JSON verdict.  A failed check, a `FrontDoor` ticket that carries
 an exception, a stray ``REPRO_EVAL_BACKEND`` / ``REPRO_MESH``, or a
 platform other than TPU exits nonzero without it.  JAX's compile cache
@@ -45,6 +49,8 @@ TEST_SEED, NUM_QUERIES, BOUND = 777, 16, 0.05
 MIN_COVERAGE = 0.9
 SUM_RTOL, SUM_ATOL = 1e-5, 1e-4  # as tests/test_query_device.py
 MEASURE_TOL = 2e-4  # as tests/test_ingest_parity.py
+GBDT_TOL = 1e-4  # forest predictions, rtol = atol (labels are O(1)–O(10))
+PICKER = {"num_trees": 24, "tree_depth": 4}  # examples/aqp_service.py
 # top-level lowering + XLA compile (trace events nest, so they are left out)
 COMPILE_EVENTS = (
     "/jax/core/compile/jaxpr_to_mlir_module_duration",
@@ -123,7 +129,7 @@ def prepare(sess, num_train_queries: int = 60, **picker):
     from repro.core.picker import PickerConfig
     from repro.queries.generator import WorkloadSpec
 
-    config = PickerConfig(**{"num_trees": 24, "tree_depth": 4, **picker})
+    config = PickerConfig(**{**PICKER, **picker})
     return sess.prepare(
         WorkloadSpec(sess.table, seed=SEED), num_train_queries=num_train_queries,
         picker_config=config,
@@ -175,8 +181,51 @@ def sketches_agree(host, dev) -> list[str]:
     return bad
 
 
-def served_line(i: int, spec, ans, truth_ans, timing: str) -> tuple[str, bool]:
-    """One served answer against the exact one → (line, met its bound)."""
+def forests_agree(table, sketches, queries, host_ans) -> tuple[str, bool]:
+    """Fit the importance funnel on host and device → (report, allclose).
+
+    The funnel is fit as `Session.prepare` fits it, on the features and
+    exact contributions of ``queries``; each device forest's predictions
+    on the training rows are held to the host forest's.
+    """
+    import numpy as np
+
+    from repro.core.features import FeatureBuilder
+    from repro.core.funnel import train_funnel
+
+    fb = FeatureBuilder(table, sketches)
+    feats = [fb.features(q) for q in queries]
+    contribs = [a.contribution() for a in host_ans]
+    x = np.concatenate(feats)
+    fit, secs = {}, {}
+    for backend in ("host", "device"):
+        t0 = time.perf_counter()
+        fit[backend] = train_funnel(
+            feats, contribs, num_trees=PICKER["num_trees"], depth=PICKER["tree_depth"],
+            backend=backend,
+        ).forests
+        secs[backend] = time.perf_counter() - t0
+    splits = leaves = 0
+    worst, ok = 0.0, True
+    for h, d in zip(fit["host"], fit["device"]):
+        splits += np.array_equal(h.feat, d.feat) and np.array_equal(h.thr, d.thr)
+        leaves += np.array_equal(h.leaf, d.leaf)
+        ph, pd = h.predict(x), d.predict(x)
+        worst = max(worst, float(np.abs(pd - ph).max()))
+        ok &= bool(np.allclose(pd, ph, rtol=GBDT_TOL, atol=GBDT_TOL))
+    n = len(fit["host"])
+    report = (
+        f"gbdt: {n} forests x {PICKER['num_trees']} trees on {x.shape[0]} rows, "
+        f"host {secs['host']:.3f}s, device {secs['device']:.3f}s, "
+        f"splits identical {splits}/{n}, leaves bitwise {leaves}/{n}, "
+        f"pred max abs diff {worst:.3e}"
+    )
+    return report, ok
+
+
+def served_line(i: int, spec, ans, truth_ans, timing: str) -> tuple[str, bool, bool]:
+    """One served answer against the exact one →
+    (line, met its bound, the exact answer has a cell to estimate)."""
     import numpy as np
 
     from repro.queries.engine import error_metrics
@@ -200,7 +249,7 @@ def served_line(i: int, spec, ans, truth_ans, timing: str) -> tuple[str, bool]:
         f"{'<=' if met else '>'} {spec.error_bound} "
         f"ci_covers {covered}/{int(live.sum())}"
     )
-    return line, met
+    return line, met, bool(live.any())
 
 
 # --------------------------------------------------------------------------
@@ -214,7 +263,7 @@ def run_one_chip(clock: CompileClock) -> None:
     from repro.core import ingest
     from repro.core.sketches import build_sketches
     from repro.queries import device
-    from repro.queries.engine import per_partition_answers_batch, stack_partitions
+    from repro.queries.engine import EvalCache, per_partition_answers_batch, stack_partitions
     from repro.serving import FrontDoor
 
     opts = ps3.ExecOptions()
@@ -250,7 +299,9 @@ def run_one_chip(clock: CompileClock) -> None:
 
     specs = test_specs(table)
     half = len(specs) // 2
-    device.HOST_FALLBACKS.clear()
+    # queries the device eval would send to the host path instead
+    _, fallback = device._plan_workload(table, [s.query for s in specs], EvalCache(table))
+    fallbacks = [f"q{i:02d} {q.describe()}" for i, q in fallback]
     served: list[tuple[int, object, str]] = []  # (index, answer, timing)
     with Phase("c1 Session.execute", clock) as ph:
         for i, spec in enumerate(specs[:half]):
@@ -280,7 +331,6 @@ def run_one_chip(clock: CompileClock) -> None:
             f"{len(tickets)} tickets in {wall:.3f}s, completed {st['completed']}, "
             f"degraded {st['degraded_answers']}, shed {st['sheds']}"
         )
-    fallbacks = dict(device.HOST_FALLBACKS)
 
     with Phase("d exactness", clock) as ph:
         queries = [s.query for s in specs]
@@ -301,23 +351,32 @@ def run_one_chip(clock: CompileClock) -> None:
         bad_sk = sketches_agree(host_sk, sess.sketches.sketches())
         ph.notes.append(f"sketches: host {time.perf_counter() - t0:.3f}s, "
                         f"{len(bad_sk)} mismatched fields")
+        gbdt, forests_ok = forests_agree(table, sess.sketches.sketches(), queries, host_ans)
+        ph.notes.append(gbdt)
 
-    met = []
+    met, empty = [], []
     for i, ans, timing in sorted(served, key=lambda s: s[0]):
-        line, ok = served_line(i, specs[i], ans, host_ans[i], timing)
-        met.append(ok)
+        line, ok, tested = served_line(i, specs[i], ans, host_ans[i], timing)
+        if tested:
+            met.append(ok)
+        else:
+            empty.append(f"q{i:02d}")
         log(line)
+    check(bool(met), "no served query has a non-empty exact answer")
     coverage = sum(met) / len(met)
     reads = [ans.partitions_read for _, ans, _ in served]
-    log(f"[serve] {len(met)} answers, {sum(met)} within {BOUND} (coverage {coverage:.4f}), "
+    log(f"[serve] {len(served)} answers: {len(met)} with a non-empty exact answer, "
+        f"{sum(met)} of them within {BOUND} (coverage {coverage:.4f}); "
+        f"{len(empty)} with an empty one {empty}; "
         f"mean partitions read {sum(reads) / len(reads):.2f}/{PARTITIONS}")
-    log(f"[serve] host fallbacks: {sum(fallbacks.values())} {fallbacks}")
+    log(f"[serve] host fallbacks: {len(fallbacks)} {fallbacks}")
     for m in mismatched:
         log(f"[d exactness] MISMATCH {m}")
     for b in bad_sk:
         log(f"[d exactness] SKETCH MISMATCH {b}")
     check(not mismatched, f"{len(mismatched)} per-partition answers differ from host")
     check(not bad_sk, f"sketch fields differ from host: {bad_sk}")
+    check(forests_ok, f"device GBDT forests differ from host beyond {GBDT_TOL}")
     check(coverage >= MIN_COVERAGE, f"coverage {coverage:.3f} < {MIN_COVERAGE}")
 
 
@@ -360,10 +419,6 @@ def run_mesh(clock: CompileClock, chips: int) -> None:
         if (why := answers_agree(a, b, exact=True)) is not None
     ]
     log(f"[m2] per-partition answers bitwise equal: {not answers_diff} {answers_diff}")
-    within = [
-        f"q{i:02d}" for i, (a, b) in enumerate(zip(ans["one"], ans["mesh"]))
-        if answers_agree(a, b) is not None
-    ]
 
     served = {}
     for name, opts in (("one", one), ("mesh", mesh)):
@@ -375,14 +430,17 @@ def run_mesh(clock: CompileClock, chips: int) -> None:
     exec_diff = [
         f"q{i:02d}" for i, (a, b) in enumerate(zip(served["one"], served["mesh"]))
         if not (np.array_equal(a.group_keys, b.group_keys)
-                and np.array_equal(a.estimate, b.estimate)
+                and np.array_equal(a.estimate, b.estimate, equal_nan=True)
                 and a.partitions_read == b.partitions_read)
     ]
     log(f"[m3] Session.execute estimates bitwise equal: {not exec_diff} {exec_diff}")
-    log(f"[mesh] verdict: statistics {'bitwise' if not diff else 'DIFFER'}, answers "
-        f"{'bitwise' if not answers_diff else 'within tolerance' if not within else 'DIFFER'}"
-        f", execute {'bitwise' if not exec_diff else 'DIFFER'}")
-    check(not within, f"mesh answers differ beyond tolerance: {within}")
+    # a partition's reductions stay on one device in the same f32 order,
+    # so the mesh owes bitwise equality (docs/architecture.md, Invariants)
+    check(not diff, f"mesh sketch statistics differ from one device: {diff}")
+    check(not answers_diff, f"mesh per-partition answers differ: {answers_diff}")
+    check(not exec_diff, f"mesh Session.execute estimates differ: {exec_diff}")
+    log("[mesh] verdict: statistics, per-partition answers and execute estimates "
+        "bitwise equal to one device")
 
 
 def main() -> int:

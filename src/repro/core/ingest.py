@@ -47,7 +47,7 @@ _ROW_SPEC = dataplane.partition_spec(2, 0)  # (P, k) tensors: shard axis 0
 
 
 def _moments_core(x, *, use_ref):
-    """(P, R) → (P, 8) kernel moments; P is whatever shard this sees."""
+    """(P, R) → (P, 10) kernel moments; P is whatever shard this sees."""
     TRACES.note("moments", *x.shape)
     return ops.moments_op(x, use_ref=use_ref)
 

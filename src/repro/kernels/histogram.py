@@ -29,6 +29,8 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.common import LANE, SUBLANE, interpret, pick_block, round_up
 
+_BLOCK_BINS = 512  # bincount's bin tile: wide cardinalities take several
+
 
 def _popcount(onehot: jax.Array) -> jax.Array:
     """(bins, bt) 0/1 → (8, bins) row sums, every row identical."""
@@ -97,15 +99,13 @@ def _bincount_kernel(codes_ref, o_ref, *, bb: int):
     o_ref[0] += _popcount(c == bins)  # (bb, bt) one-hot
 
 
-@functools.partial(jax.jit, static_argnames=("card", "block_rows", "block_bins"))
-def bincount(
-    codes: jax.Array, card: int, block_rows: int = 1024, block_bins: int = 512
-) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("card", "block_rows"))
+def bincount(codes: jax.Array, card: int, block_rows: int = 1024) -> jax.Array:
     """(P, R) int codes in [0, card) → (P, card) exact counts."""
     p, r = codes.shape
     bt = pick_block(r, block_rows, LANE)
     rp = round_up(r, bt)
-    bb = pick_block(card, block_bins, LANE)
+    bb = pick_block(card, _BLOCK_BINS, LANE)
     bpad = round_up(card, bb)
     cp = jnp.pad(codes.astype(jnp.int32), ((0, 0), (0, rp - r)),
                  constant_values=-1)[:, None, :]

@@ -57,7 +57,6 @@ compile-bound test asserts the census, `bench_offline` reports it.
 """
 from __future__ import annotations
 
-import collections
 import dataclasses
 
 import jax
@@ -73,8 +72,6 @@ from repro.queries import engine
 from repro.queries.ir import Aggregate, Predicate, Query
 
 TRACES = TraceRegistry("query_eval")
-# queries `eval_workload` sent to the host path instead ({describe(): n})
-HOST_FALLBACKS: collections.Counter = collections.Counter()
 
 # cap on stacked f32 elements per launch (Q_b · N · max(C_b, V_b) · R)
 MAX_STACK_ELEMS = 1 << 25
@@ -574,7 +571,6 @@ def eval_workload(
     grouped, fallback = _plan_workload(table, queries, cache)
     out: list[engine.PartitionAnswers | None] = [None] * len(queries)
     for i, q in fallback:  # inexpressible predicates: exact-parity host path
-        HOST_FALLBACKS[q.describe()] += 1
         out[i] = engine._host_answers(table, q, cache)
     if use_ref is None and cache.plane is None and jax.default_backend() != "tpu":
         for _sig, entries in grouped.items():
