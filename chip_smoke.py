@@ -14,9 +14,9 @@ One chip, one process, in order:
      ``Session.execute``, half through a started `FrontDoor` on the real
      clock;
   d. hold the chip to host numpy: the served queries' per-partition
-     answers (counts exact, sums rtol 1e-5), the sketches (allclose) and
-     the importance funnel fit on those queries (the device forests'
-     training loss within 10 % of the host forests').
+     answers (counts exact, sums rtol 1e-5) and the sketches (allclose);
+     report how the importance funnel fit on those queries on the device
+     compares with the host fit.
 
 With ``--chips 4`` it runs only the partition-mesh path — sketch
 statistics, per-partition answers and a few ``Session.execute`` calls
@@ -50,7 +50,6 @@ TEST_SEED, NUM_QUERIES, BOUND = 777, 16, 0.05
 MIN_COVERAGE = 0.9
 SUM_RTOL, SUM_ATOL = 1e-5, 1e-4  # as tests/test_query_device.py
 MEASURE_TOL = 2e-4  # as tests/test_ingest_parity.py
-GBDT_LOSS_TOL = 0.1  # device funnel forests' training loss, relative to host
 PICKER = {"num_trees": 24, "tree_depth": 4}  # examples/aqp_service.py
 # top-level lowering + XLA compile (trace events nest, so they are left out)
 COMPILE_EVENTS = (
@@ -182,17 +181,15 @@ def sketches_agree(host, dev) -> list[str]:
     return bad
 
 
-def forests_agree(table, sketches, queries, host_ans) -> tuple[str, bool]:
-    """Fit the importance funnel on host and device → (report, device fit
-    holds up).
+def compare_forests(table, sketches, queries, host_ans) -> str:
+    """Fit the importance funnel on host and device → a report line.
 
     The funnel is fit as `Session.prepare` fits it, on the features and
-    exact contributions of ``queries``.  The fit is not stable under f32
-    rounding: where split gains nearly tie, re-associated histogram sums
-    (the MXU's order on a TPU) pick another split and every later tree
-    follows, so the forests differ in structure and their predictions are
-    not allclose.  What is held is the fit's quality: each device forest's
-    mean squared error on its training labels is within GBDT_LOSS_TOL of
+    exact contributions of ``queries``.  No agreement is promised, so
+    nothing is checked: where split gains nearly tie, re-associated
+    histogram sums (the MXU's order on a TPU) pick another split and every
+    later tree follows.  The line gives matching split structures, the
+    largest prediction gap and each device forest's training loss over
     the host forest's.
     """
     import numpy as np
@@ -223,14 +220,13 @@ def forests_agree(table, sketches, queries, host_ans) -> tuple[str, bool]:
         worst = max(worst, float(np.abs(pd - ph).max()))
         ratios.append(float(np.mean((pd - y) ** 2) / np.mean((ph - y) ** 2)))
     n = len(host.forests)
-    report = (
+    return (
         f"gbdt: {n} forests x {PICKER['num_trees']} trees on {x.shape[0]} rows, "
         f"host {secs['host']:.3f}s, device {secs['device']:.3f}s, "
         f"splits identical {splits}/{n}, leaves bitwise {leaves}/{n}, "
         f"pred max abs diff {worst:.3e}, device/host training loss "
         + " ".join(f"{r:.4f}" for r in ratios)
     )
-    return report, max(ratios) <= 1 + GBDT_LOSS_TOL
 
 
 def served_line(i: int, spec, ans, truth_ans, timing: str) -> tuple[str, bool, bool]:
@@ -361,8 +357,7 @@ def run_one_chip(clock: CompileClock) -> None:
         bad_sk = sketches_agree(host_sk, sess.sketches.sketches())
         ph.notes.append(f"sketches: host {time.perf_counter() - t0:.3f}s, "
                         f"{len(bad_sk)} mismatched fields")
-        gbdt, forests_ok = forests_agree(table, sess.sketches.sketches(), queries, host_ans)
-        ph.notes.append(gbdt)
+        ph.notes.append(compare_forests(table, sess.sketches.sketches(), queries, host_ans))
 
     met, empty = [], []
     for i, ans, timing in sorted(served, key=lambda s: s[0]):
@@ -386,8 +381,6 @@ def run_one_chip(clock: CompileClock) -> None:
         log(f"[d exactness] SKETCH MISMATCH {b}")
     check(not mismatched, f"{len(mismatched)} per-partition answers differ from host")
     check(not bad_sk, f"sketch fields differ from host: {bad_sk}")
-    check(forests_ok, f"a device funnel forest's training loss exceeds the host's "
-                      f"by more than {GBDT_LOSS_TOL:.0%}")
     check(coverage >= MIN_COVERAGE, f"coverage {coverage:.3f} < {MIN_COVERAGE}")
 
 

@@ -29,9 +29,11 @@ apply the boosting update as a separately-rounded ``lr·leaf`` host-side
 step (XLA would contract the fused multiply-add into an FMA, which numpy
 cannot express).  The exported forest is therefore *bit-identical* across
 backends on the same binned codes (tested elementwise), so the predict
-path and `core/funnel.py` calibration are backend-independent.  On real
-TPU the Pallas kernel's MXU contraction reorders the sums; there parity is
-allclose, not bitwise (same caveat as every other kernel in the layer).
+path and `core/funnel.py` calibration are backend-independent.  On a
+real TPU the Pallas kernel's MXU contraction reorders the sums, and where
+split gains nearly tie that picks other splits, which every later tree
+follows: there the forest is neither bitwise nor allclose to the host's
+(`chip_smoke.py` reports how far apart they land).
 
 ``fit_gbdt(..., parity_relaxation=True)`` (surfaced as
 ``ExecOptions.parity_relaxation``) trades that contract for speed: the
