@@ -37,6 +37,7 @@ import dataclasses
 import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import faults
 from repro.core.funnel import allocate
@@ -94,6 +95,11 @@ class QueryPlan:
     # serving plane: escalation stopped by a wall-clock deadline (the
     # answer is the best estimate produced before it expired)
     deadline_hit: bool = False
+    # read cost: chunk reads issued, bytes of the arrays `_merge_raw`
+    # allocated, bytes the chunk reads sent host to device
+    chunk_reads: int = 0
+    merge_bytes: int = 0
+    upload_bytes: int = 0
 
 
 @dataclasses.dataclass
@@ -106,6 +112,15 @@ class PlannedAnswer:
     ci_halfwidth: np.ndarray  # (G, n_aggs); 0 where exact
     partitions_read: int
     plan: QueryPlan
+
+
+@dataclasses.dataclass
+class _ReadCost:
+    """One answer's running read cost, copied onto its `QueryPlan`."""
+
+    chunk_reads: int = 0
+    merge_bytes: int = 0
+    upload_bytes: int = 0
 
 
 def _merge_raw(keys_a, raw_a, keys_b, raw_b):
@@ -137,6 +152,7 @@ class QueryPlanner:
         self.views = views
         self.config = config or PlannerConfig()
         self.chunk_evals = 0  # telemetry: chunk reads issued
+        self.merge_bytes = 0  # telemetry: bytes `_merge_raw` allocated
         # fault-aware reads: the injector (None when ExecOptions.faults is
         # unset) gates every chunk read; irrecoverable partitions are
         # masked inside the padded chunk shapes and the answer degrades —
@@ -144,7 +160,7 @@ class QueryPlanner:
         self.injector = faults.injector_for(answers.options)
 
     # ---- read path --------------------------------------------------------
-    def _read(self, query, new_ids, state, failed: set | None = None):
+    def _read(self, query, new_ids, state, failed: set, cost: _ReadCost):
         """Evaluate `new_ids` in fixed-`chunk`-size subset views and fold
         them into the accumulated (keys, raw, row_of) state.  Chunks are
         padded by repeating the first id, so every chunk ships exactly
@@ -156,25 +172,35 @@ class QueryPlanner:
         partitions that exhaust their retries land in ``failed`` and are
         masked *inside* the same padded chunk shape — the survivors pad
         to exactly ``config.chunk`` as before, so failures never mint a
-        new shape bucket or re-trace (the compile census stays flat)."""
+        new shape bucket or re-trace (the compile census stays flat).
+
+        ``cost`` adds up the reads, merge allocations and uploads; the
+        uploads are the store's count before and after each read, exact
+        while one thread drives the store (the front door's flush loop)."""
         chunk = self.config.chunk
         keys, raw, row_of = state
-        for lo in range(0, len(new_ids), chunk):
-            ids = np.asarray(new_ids[lo:lo + chunk], dtype=np.int64)
-            if self.injector is not None:
-                ids, lost = self.injector.read_ids(ids)
-                if failed is not None:
+        with TraceAnnotation("planner.read"):
+            for lo in range(0, len(new_ids), chunk):
+                ids = np.asarray(new_ids[lo:lo + chunk], dtype=np.int64)
+                if self.injector is not None:
+                    ids, lost = self.injector.read_ids(ids)
                     failed.update(int(i) for i in lost)
-                if ids.size == 0:
-                    continue  # whole chunk dead: nothing to evaluate
-            n_real = ids.size
-            if n_real < chunk:
-                ids = np.concatenate([ids, np.full(chunk - n_real, ids[0])])
-            ans = self.answers.get_subset(query, ids)
-            self.chunk_evals += 1
-            keys, raw = _merge_raw(keys, raw, ans.group_keys, ans.raw[:n_real])
-            for i in ids[:n_real]:
-                row_of[int(i)] = len(row_of)
+                    if ids.size == 0:
+                        continue  # whole chunk dead: nothing to evaluate
+                n_real = ids.size
+                if n_real < chunk:
+                    ids = np.concatenate([ids, np.full(chunk - n_real, ids[0])])
+                uploaded = self.answers.upload_bytes
+                ans = self.answers.get_subset(query, ids)
+                cost.upload_bytes += self.answers.upload_bytes - uploaded
+                self.chunk_evals += 1
+                cost.chunk_reads += 1
+                with TraceAnnotation("planner.merge"):
+                    keys, raw = _merge_raw(keys, raw, ans.group_keys, ans.raw[:n_real])
+                    cost.merge_bytes += keys.nbytes + raw.nbytes
+                    self.merge_bytes += keys.nbytes + raw.nbytes
+                    for i in ids[:n_real]:
+                        row_of[int(i)] = len(row_of)
         return keys, raw, row_of
 
     # ---- planning ---------------------------------------------------------
@@ -202,116 +228,118 @@ class QueryPlanner:
           best answer produced so far with ``plan.deadline_hit`` /
           ``plan.degraded`` set and the honest (wider) interval.
         """
-        if (error_bound is None) == (budget is None):
-            raise InvalidQueryError("pass exactly one of error_bound= / budget=")
-        if budget_cap is not None and budget_cap < 1:
-            raise InvalidQueryError(f"budget_cap must be >= 1, got {budget_cap}")
-        if deadline is not None and clock is None:
-            clock = time.monotonic
-        if deadline is not None and strict and clock() >= deadline:
-            # expired before any read: shed the whole plan, zero cost
-            raise DeadlineExceededError(
-                f"deadline expired {clock() - deadline:.3f}s before "
-                "planning began",
-                predicted_error=None,
-                partitions_read=0,
-            )
-        if budget is not None and budget_cap is not None:
-            budget = min(int(budget), int(budget_cap))
-        cfg = self.config
-        plans, n_raw = plan_aggregates(query.aggregates)
-        n_aggs = len(plans)
-        radix = group_radix_checked(self.fb.table, query.groupby)
+        with TraceAnnotation("planner.select"):
+            if (error_bound is None) == (budget is None):
+                raise InvalidQueryError("pass exactly one of error_bound= / budget=")
+            if budget_cap is not None and budget_cap < 1:
+                raise InvalidQueryError(f"budget_cap must be >= 1, got {budget_cap}")
+            if deadline is not None and clock is None:
+                clock = time.monotonic
+            if deadline is not None and strict and clock() >= deadline:
+                # expired before any read: shed the whole plan, zero cost
+                raise DeadlineExceededError(
+                    f"deadline expired {clock() - deadline:.3f}s before "
+                    "planning began",
+                    predicted_error=None,
+                    partitions_read=0,
+                )
+            if budget is not None and budget_cap is not None:
+                budget = min(int(budget), int(budget_cap))
+            cfg = self.config
+            plans, n_raw = plan_aggregates(query.aggregates)
+            n_aggs = len(plans)
+            radix = group_radix_checked(self.fb.table, query.groupby)
 
-        # 1. view store: exact answer = zero partitions read
-        if self.views is not None:
-            hit = self.views.answer(query)
-            if hit is not None:
-                keys, est = hit
-                plan = QueryPlan("view", error_bound, budget, 0, (), 0, 0, (), 0.0)
+            # 1. view store: exact answer = zero partitions read
+            if self.views is not None:
+                hit = self.views.answer(query)
+                if hit is not None:
+                    keys, est = hit
+                    plan = QueryPlan("view", error_bound, budget, 0, (), 0, 0, (), 0.0)
+                    return PlannedAnswer(
+                        query, keys, est, np.zeros_like(est), 0, plan
+                    )
+                caps = self.views.upper_bounds(query)
+            else:
+                caps = None
+
+            # 2. candidates (perfect-recall selectivity filter) + must-reads
+            sel = self.fb.selectivity(query)
+            feats = self.fb.features(query)
+            # live-mask filter: tombstoned partitions leave the candidate set
+            # (and hence every stratum population N_h), so estimates and CI
+            # halfwidths stay honest after deletes without a rebuild
+            candidates = np.flatnonzero(
+                (sel[:, 0] > 0) & self.fb.table.live_mask()
+            )
+            if candidates.size == 0:
+                plan = QueryPlan("empty", error_bound, budget, 0, (), 0, 0, (), 0.0)
                 return PlannedAnswer(
-                    query, keys, est, np.zeros_like(est), 0, plan
+                    query, np.empty(0, np.int64), np.zeros((0, n_aggs)),
+                    np.zeros((0, n_aggs)), 0, plan,
                 )
-            caps = self.views.upper_bounds(query)
-        else:
-            caps = None
-
-        # 2. candidates (perfect-recall selectivity filter) + must-reads
-        sel = self.fb.selectivity(query)
-        feats = self.fb.features(query)
-        # live-mask filter: tombstoned partitions leave the candidate set
-        # (and hence every stratum population N_h), so estimates and CI
-        # halfwidths stay honest after deletes without a rebuild
-        candidates = np.flatnonzero(
-            (sel[:, 0] > 0) & self.fb.table.live_mask()
-        )
-        if candidates.size == 0:
-            plan = QueryPlan("empty", error_bound, budget, 0, (), 0, 0, (), 0.0)
-            return PlannedAnswer(
-                query, np.empty(0, np.int64), np.zeros((0, n_aggs)),
-                np.zeros((0, n_aggs)), 0, plan,
-            )
-        # 3. first rung: the sketch prior forecasts grand-total variance,
-        # which is far more pessimistic than the per-group relative metric
-        # on easy queries — cap it and let the measured CI (which sees the
-        # actual per-group spreads) drive escalation from there.
-        if budget is not None:
-            rung0 = max(1, min(int(budget), candidates.size))
-            rounds_left = 1
-        else:
-            prior = prior_budget(
-                query, self.fb.sk, sel, candidates, error_bound, cfg.z,
-                self.fb.table.rows_per_partition, radix,
-            )
-            cap0 = max(cfg.min_budget, candidates.size // 4)
-            total0 = int(min(max(cfg.min_budget, prior), cap0, candidates.size))
-            rung0 = total0
-            rounds_left = 64  # geometric growth: hits |inliers| well before
-        # must-reads: rare-group partitions, capped relative to the rung
-        # (not the candidate count — a probably-empty query must not sink
-        # 20% of the table into outlier reads before its first estimate)
-        outlier_ids = np.empty(0, np.int64)
-        max_out = max(1, int(cfg.outlier_frac * rung0))
-        if query.groupby:
-            bits = self.picker._gb_bitmaps(query, candidates)
-            outlier_ids = find_outliers(candidates, bits, max_out)
-        failed: set[int] = set()
-        state = (np.empty(0, np.int64), np.zeros((0, 0, n_raw)), {})
-        if outlier_ids.size:
-            state = self._read(query, outlier_ids, state, failed)
-            # outlier substitution: a failed must-read is often not the
-            # only partition holding its rare groups — recompute the
-            # outlier cover over the still-readable candidates and read
-            # the substitute holders.  Runs BEFORE strata are built so
-            # substitutes join the weight-1 outlier set instead of
-            # double-counting inside a stratum's expansion.  Terminates:
-            # each pass reads only never-attempted ids.
-            while failed:
-                alive = candidates[~np.isin(
-                    candidates, np.fromiter(failed, np.int64, len(failed))
-                )]
-                subs = find_outliers(
-                    alive, self.picker._gb_bitmaps(query, alive), max_out
+            # 3. first rung: the sketch prior forecasts grand-total variance,
+            # which is far more pessimistic than the per-group relative metric
+            # on easy queries — cap it and let the measured CI (which sees the
+            # actual per-group spreads) drive escalation from there.
+            if budget is not None:
+                rung0 = max(1, min(int(budget), candidates.size))
+                rounds_left = 1
+            else:
+                prior = prior_budget(
+                    query, self.fb.sk, sel, candidates, error_bound, cfg.z,
+                    self.fb.table.rows_per_partition, radix,
                 )
-                subs = np.setdiff1d(subs, outlier_ids)
-                if subs.size == 0:
-                    break
-                outlier_ids = np.union1d(outlier_ids, subs)
-                state = self._read(query, subs, state, failed)
-        inliers = np.setdiff1d(candidates, outlier_ids)
-        # brownout clamp: escalation may never grow past `limit` sampled
-        # partitions, however far the bound would like to go.  Floor of 2
-        # keeps sample variances defined (matching total0 below).
-        limit = int(inliers.size)
-        if budget_cap is not None:
-            limit = min(limit, max(2, int(budget_cap) - int(outlier_ids.size)))
-        strata = self.funnel.classify(feats, inliers)
-        strata = [s for s in strata if s.size]
-        if not strata:
-            strata = [inliers]
-        sizes = [s.size for s in strata]
-        rng = np.random.default_rng(cfg.seed)
-        perms = [s[rng.permutation(s.size)] for s in strata]
+                cap0 = max(cfg.min_budget, candidates.size // 4)
+                total0 = int(min(max(cfg.min_budget, prior), cap0, candidates.size))
+                rung0 = total0
+                rounds_left = 64  # geometric growth: hits |inliers| well before
+            # must-reads: rare-group partitions, capped relative to the rung
+            # (not the candidate count — a probably-empty query must not sink
+            # 20% of the table into outlier reads before its first estimate)
+            outlier_ids = np.empty(0, np.int64)
+            max_out = max(1, int(cfg.outlier_frac * rung0))
+            if query.groupby:
+                bits = self.picker._gb_bitmaps(query, candidates)
+                outlier_ids = find_outliers(candidates, bits, max_out)
+            failed: set[int] = set()
+            cost = _ReadCost()
+            state = (np.empty(0, np.int64), np.zeros((0, 0, n_raw)), {})
+            if outlier_ids.size:
+                state = self._read(query, outlier_ids, state, failed, cost)
+                # outlier substitution: a failed must-read is often not the
+                # only partition holding its rare groups — recompute the
+                # outlier cover over the still-readable candidates and read
+                # the substitute holders.  Runs BEFORE strata are built so
+                # substitutes join the weight-1 outlier set instead of
+                # double-counting inside a stratum's expansion.  Terminates:
+                # each pass reads only never-attempted ids.
+                while failed:
+                    alive = candidates[~np.isin(
+                        candidates, np.fromiter(failed, np.int64, len(failed))
+                    )]
+                    subs = find_outliers(
+                        alive, self.picker._gb_bitmaps(query, alive), max_out
+                    )
+                    subs = np.setdiff1d(subs, outlier_ids)
+                    if subs.size == 0:
+                        break
+                    outlier_ids = np.union1d(outlier_ids, subs)
+                    state = self._read(query, subs, state, failed, cost)
+            inliers = np.setdiff1d(candidates, outlier_ids)
+            # brownout clamp: escalation may never grow past `limit` sampled
+            # partitions, however far the bound would like to go.  Floor of 2
+            # keeps sample variances defined (matching total0 below).
+            limit = int(inliers.size)
+            if budget_cap is not None:
+                limit = min(limit, max(2, int(budget_cap) - int(outlier_ids.size)))
+            strata = self.funnel.classify(feats, inliers)
+            strata = [s for s in strata if s.size]
+            if not strata:
+                strata = [inliers]
+            sizes = [s.size for s in strata]
+            rng = np.random.default_rng(cfg.seed)
+            perms = [s[rng.permutation(s.size)] for s in strata]
         total0 = max(0 if budget is not None else 2, rung0 - outlier_ids.size)
         total0 = min(total0, limit)
         taken = [0] * len(strata)  # ATTEMPTED prefix per stratum (failed
@@ -324,17 +352,20 @@ class QueryPlanner:
         scales = None
         deadline_hit = False
         while True:
-            alloc = self._allocate(sizes, total, scales)
-            new_ids: list[int] = []
-            for h, n_h in enumerate(alloc):
-                n_h = max(taken[h], n_h)  # prefix reuse: never shrink
-                if sizes[h] > n_h >= sizes[h] - 1:
-                    n_h = sizes[h]  # don't leave a lone unread partition
-                want[h] = max(want[h], n_h)
-                new_ids.extend(int(i) for i in perms[h][taken[h]:n_h])
-                taken[h] = max(taken[h], n_h)
+            # a round's planning is two `planner.estimate` spans, one each
+            # side of its reads, so the reads never nest inside one
+            with TraceAnnotation("planner.estimate"):
+                alloc = self._allocate(sizes, total, scales)
+                new_ids: list[int] = []
+                for h, n_h in enumerate(alloc):
+                    n_h = max(taken[h], n_h)  # prefix reuse: never shrink
+                    if sizes[h] > n_h >= sizes[h] - 1:
+                        n_h = sizes[h]  # don't leave a lone unread partition
+                    want[h] = max(want[h], n_h)
+                    new_ids.extend(int(i) for i in perms[h][taken[h]:n_h])
+                    taken[h] = max(taken[h], n_h)
             if new_ids:
-                state = self._read(query, new_ids, state, failed)
+                state = self._read(query, new_ids, state, failed, cost)
             # replacement substitution: when reads failed, extend each
             # stratum's attempted prefix until the SURVIVING count reaches
             # its allocation target (or the stratum runs out of ids).
@@ -350,29 +381,30 @@ class QueryPlanner:
                         taken[h] = stop
                 if not repl:
                     break
-                state = self._read(query, repl, state, failed)
-            schedule.append(sum(taken))
-            keys, raw, row_of = state
-            sampled = [p[:t] for p, t in zip(perms, taken)]
-            if failed:
-                # degraded weighting: SRSWOR weights re-expand over the
-                # surviving sample per stratum — N_h/n_h with n_h the
-                # survivors, while N_h keeps the full population
-                fail_arr = np.fromiter(failed, np.int64, len(failed))
-                sampled = [s[~np.isin(s, fail_arr)] for s in sampled]
-            n_survived = sum(s.size for s in sampled)
-            frac_unread = 1.0 - n_survived / max(inliers.size, 1)
-            outlier_read = outlier_ids
-            if failed and outlier_ids.size:
-                outlier_read = outlier_ids[~np.isin(outlier_ids, fail_arr)]
-            est = stratified_answer(
-                query, plans, keys, raw, row_of, outlier_read,
-                strata, sampled, cfg.z, frac_unread, n_failed=len(failed),
-            )
-            scales = est.stratum_scales
-            estimate, hw, predicted = self._apply_caps(
-                query, caps, est, n_aggs
-            )
+                state = self._read(query, repl, state, failed, cost)
+            with TraceAnnotation("planner.estimate"):
+                schedule.append(sum(taken))
+                keys, raw, row_of = state
+                sampled = [p[:t] for p, t in zip(perms, taken)]
+                if failed:
+                    # degraded weighting: SRSWOR weights re-expand over the
+                    # surviving sample per stratum — N_h/n_h with n_h the
+                    # survivors, while N_h keeps the full population
+                    fail_arr = np.fromiter(failed, np.int64, len(failed))
+                    sampled = [s[~np.isin(s, fail_arr)] for s in sampled]
+                n_survived = sum(s.size for s in sampled)
+                frac_unread = 1.0 - n_survived / max(inliers.size, 1)
+                outlier_read = outlier_ids
+                if failed and outlier_ids.size:
+                    outlier_read = outlier_ids[~np.isin(outlier_ids, fail_arr)]
+                est = stratified_answer(
+                    query, plans, keys, raw, row_of, outlier_read,
+                    strata, sampled, cfg.z, frac_unread, n_failed=len(failed),
+                )
+                scales = est.stratum_scales
+                estimate, hw, predicted = self._apply_caps(
+                    query, caps, est, n_aggs
+                )
             rounds_left -= 1
             done_all = all(t >= s for t, s in zip(taken, sizes))
             if deadline is not None and clock() >= deadline:
@@ -437,6 +469,9 @@ class QueryPlanner:
             failed_ids=tuple(sorted(failed)),
             read_report=self.injector.report() if self.injector else {},
             deadline_hit=deadline_hit,
+            chunk_reads=cost.chunk_reads,
+            merge_bytes=cost.merge_bytes,
+            upload_bytes=cost.upload_bytes,
         )
         return PlannedAnswer(
             query, est.group_keys if mode != "hybrid" else self._cap_keys(est, caps),

@@ -62,6 +62,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.clustering import bucket_size
 from repro.data.table import CATEGORICAL, Table
@@ -427,18 +428,24 @@ def _run_chunk(
     ncols1 = cache.ones_index + 1
     stack = cache.device_stack()
 
-    col_idx = np.zeros((qb, max(sig.num_clauses, 1)), np.int32)
-    lo = np.full((qb, max(sig.num_clauses, 1)), np.float32(1.0), np.float32)
-    hi = np.full((qb, max(sig.num_clauses, 1)), np.float32(-1.0), np.float32)
-    gmap = np.zeros(
-        (qb, max(sig.num_clauses, 1), max(sig.num_groups, 1)), np.float32
-    )
-    coefs = np.zeros((qb, sig.n_raw, ncols1), np.float32)
-    mults = np.zeros((qb, ncols1), np.float32)
-    for i, plan in enumerate(chunk):
-        col_idx[i], lo[i], hi[i], gmap[i], coefs[i], mults[i] = _descriptor(plan, cache)
+    with TraceAnnotation("eval.prepare"):
+        col_idx = np.zeros((qb, max(sig.num_clauses, 1)), np.int32)
+        lo = np.full((qb, max(sig.num_clauses, 1)), np.float32(1.0), np.float32)
+        hi = np.full((qb, max(sig.num_clauses, 1)), np.float32(-1.0), np.float32)
+        gmap = np.zeros(
+            (qb, max(sig.num_clauses, 1), max(sig.num_groups, 1)), np.float32
+        )
+        coefs = np.zeros((qb, sig.n_raw, ncols1), np.float32)
+        mults = np.zeros((qb, ncols1), np.float32)
+        for i, plan in enumerate(chunk):
+            col_idx[i], lo[i], hi[i], gmap[i], coefs[i], mults[i] = _descriptor(plan, cache)
 
     plane = cache.plane
+    # the launch ships its descriptors host → device, to every device of a mesh
+    sent = (coefs, mults) + ((col_idx, lo, hi, gmap) if sig.has_predicate else ())
+    cache.upload_bytes += sum(a.nbytes for a in sent) * (
+        1 if plane is None else plane.num_devices
+    )
     if plane is None:
         if sig.has_predicate:
             out = _eval_stacked(
@@ -470,9 +477,10 @@ def _run_chunk(
     # [:, :n] slices off the mesh's zero pad partitions (no-op unsharded)
     out = np.asarray(out, np.float64)[:, :n]
     answers = []
-    for i, plan in enumerate(chunk):
-        raw = out[i, :, : plan.n_raw, : plan.radix].transpose(0, 2, 1)
-        answers.append(engine._answers_from_raw(plan.query, raw, plan.plans))
+    with TraceAnnotation("eval.unpack"):
+        for i, plan in enumerate(chunk):
+            raw = out[i, :, : plan.n_raw, : plan.radix].transpose(0, 2, 1)
+            answers.append(engine._answers_from_raw(plan.query, raw, plan.plans))
     return answers
 
 
@@ -568,7 +576,8 @@ def eval_workload(
     from repro.backends import kernels_use_ref
 
     cache = cache or engine.EvalCache(table)
-    grouped, fallback = _plan_workload(table, queries, cache)
+    with TraceAnnotation("eval.prepare"):
+        grouped, fallback = _plan_workload(table, queries, cache)
     out: list[engine.PartitionAnswers | None] = [None] * len(queries)
     for i, q in fallback:  # inexpressible predicates: exact-parity host path
         out[i] = engine._host_answers(table, q, cache)

@@ -28,6 +28,7 @@ import time
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.backends import UNSET, ExecOptions, exec_options
 from repro.data.table import CATEGORICAL, NUMERIC, Table
@@ -292,6 +293,8 @@ class EvalCache:
         self.stack_appends = 0  # in-place slack writes (streaming appends)
         self.stack_rebuilds = 0  # full stack (re)builds incl. overflows
         self.stack_rewrites = 0  # in-bucket rewrites (compaction/rebalance)
+        self.upload_bytes = 0  # host → device: stacks, slack writes, and
+        # the eval launches' per-query descriptors (`queries.device`)
 
     # the fingerprint guard costs ~1-2 µs/column, so hot accessors only
     # re-verify every Nth sync; public batch entries (AnswerStore._sync,
@@ -495,12 +498,13 @@ class EvalCache:
             self._stack = None
             self._stack_p = 0
             return
+        delta = self._host_stack(start, n)
         self._stack = dataplane.write_partitions(
-            self._stack, self._host_stack(start, n), start, axis=1,
-            plane=self.plane,
+            self._stack, delta, start, axis=1, plane=self.plane,
         )
         self._stack_p = n
         self.stack_appends += 1
+        self.upload_bytes += delta.nbytes
 
     def _rewrite_stack(self) -> None:
         """Rewrite the device stack in place after compaction/rebalance:
@@ -531,6 +535,7 @@ class EvalCache:
         )
         self._stack_p = n
         self.stack_rewrites += 1
+        self.upload_bytes += delta.nbytes
 
     def device_stack(self) -> jax.Array:
         """(n_cols+1, P_bucket, R) float32 column stack, resident on device.
@@ -555,20 +560,22 @@ class EvalCache:
             if self._stack is None:
                 import jax.numpy as jnp
 
-                t = self.table
-                target = stack_partitions(t.num_partitions, self.plane)
-                stack = self._host_stack(0, t.num_partitions)
-                self.stack_rebuilds += 1
-                if self.plane is not None:
-                    self._stack = self.plane.shard_partitions(
-                        stack, axis=1, target=target
-                    )
-                else:
-                    pad = target - t.num_partitions
-                    if pad:
-                        stack = np.pad(stack, ((0, 0), (0, pad), (0, 0)))
-                    self._stack = jnp.asarray(stack)
-                self._stack_p = t.num_partitions
+                with TraceAnnotation("eval.upload"):
+                    t = self.table
+                    target = stack_partitions(t.num_partitions, self.plane)
+                    stack = self._host_stack(0, t.num_partitions)
+                    self.stack_rebuilds += 1
+                    if self.plane is not None:
+                        self._stack = self.plane.shard_partitions(
+                            stack, axis=1, target=target
+                        )
+                    else:
+                        pad = target - t.num_partitions
+                        if pad:
+                            stack = np.pad(stack, ((0, 0), (0, pad), (0, 0)))
+                        self._stack = jnp.asarray(stack)
+                    self._stack_p = t.num_partitions
+                    self.upload_bytes += self._stack.nbytes
             return self._stack
 
     # distinct aggregate term tuples are unbounded across a serving
@@ -671,6 +678,13 @@ class AnswerStore:
         # delta view + EvalCache per pre-append P, shared across entries
         # (and across get() calls) so one append ships one delta stack
         self._delta_caches: dict[int, tuple[Table, EvalCache]] = {}
+        self._spent_uploads = 0  # uploads of the subset and delta caches let go
+
+    @property
+    def upload_bytes(self) -> int:
+        """Bytes every `EvalCache` of this store has sent host to device."""
+        return (self._spent_uploads + self._eval_cache.upload_bytes
+                + sum(c.upload_bytes for _, c in self._delta_caches.values()))
 
     @property
     def plane(self):
@@ -727,6 +741,7 @@ class AnswerStore:
                     continue
                 self._fold_move(ev)
         self._version = self.table.version
+        self._spent_uploads += sum(c.upload_bytes for _, c in self._delta_caches.values())
         self._delta_caches.clear()  # delta views are per-version snapshots
 
     def _fold_move(self, ev: tuple) -> None:
@@ -900,13 +915,15 @@ class AnswerStore:
                 )
             else:
                 self.misses += 1
-                t = self.table
-                cols = {k: v[ids] for k, v in t.columns.items()}
-                view = Table(t.schema, cols, name=f"{t.name}/subset")
-                cache = EvalCache(view, options=self.options)
+                with TraceAnnotation("read.gather"):
+                    t = self.table
+                    cols = {k: v[ids] for k, v in t.columns.items()}
+                    view = Table(t.schema, cols, name=f"{t.name}/subset")
+                    cache = EvalCache(view, options=self.options)
                 ans = per_partition_answers(
                     view, query, cache=cache, options=self.options
                 )
+                self._spent_uploads += cache.upload_bytes
             self._partial[key] = ans
             self._partial_born[key] = self._clock()
             while len(self._partial) > self.capacity:

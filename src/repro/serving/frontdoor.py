@@ -56,6 +56,7 @@ import threading
 import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import clustering
 from repro.errors import (
@@ -484,6 +485,11 @@ class FrontDoor:
             now = self._now()
         if not batch:
             return 0
+        with TraceAnnotation("door.flush"):
+            return self._flush(batch, now)
+
+    def _flush(self, batch: list[_Request], now: float) -> int:
+        """Shed, degrade, coalesce, execute and resolve a drained batch."""
         resolved = 0
         # shed expired-in-queue requests before any partition read
         runnable: list[tuple[_Request, object, int | None, int]] = []
