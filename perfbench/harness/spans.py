@@ -7,7 +7,8 @@ served path, all on the front door's flush thread:
     door.flush        serving/frontdoor.py  FrontDoor.tick, a non-empty flush
     planner.select    planner/planner.py    QueryPlanner.answer up to the strata
     planner.read      planner/planner.py    QueryPlanner._read, the chunk loop
-    planner.merge     planner/planner.py    _merge_raw per chunk read
+    planner.merge     planner/planner.py    _merge_raw folding chunk reads into
+                                            the answer's array
     planner.estimate  planner/planner.py    a round's allocation; its estimate
     read.gather       queries/engine.py     AnswerStore.get_subset on a miss
     eval.prepare      queries/device.py     canonical predicates; descriptors
@@ -30,9 +31,7 @@ from __future__ import annotations
 
 from perfbench.harness import trace
 
-PROGRAM = ("door.flush", "planner.select", "planner.read", "planner.merge",
-           "planner.estimate", "read.gather", "eval.prepare", "eval.upload",
-           "eval.unpack")
+PROGRAM = trace.PROGRAM_SPANS
 LAUNCH = ("PjitFunction(", "DevicePut")  # name prefixes
 FETCH = "np.asarray(jax.Array)"
 

@@ -14,9 +14,15 @@ import re
 import tempfile
 
 WINDOW_SPAN = "bench.window"  # the host span the window runs inside
-# host spans the harness opens around the calls into each layer; an idle
-# gap of the device is named by the innermost of them open at its middle
-SPANS = ("bench.window", "client.wait", "serve.execute", "serve.chunk_read")
+# the program's own host spans on the served path (`harness/spans.py` says
+# where each opens and reduces them)
+PROGRAM_SPANS = ("door.flush", "planner.select", "planner.read", "planner.merge",
+                 "planner.estimate", "read.gather", "eval.prepare", "eval.upload",
+                 "eval.unpack")
+# an idle gap of the device is named by the innermost of these open at its
+# middle: the host spans the harness opens around the calls into each
+# layer, and the program's; JAX's own events are not among them
+SPANS = ("bench.window", "client.wait", "serve.execute", "serve.chunk_read") + PROGRAM_SPANS
 # device lines whose events are operations running on the chip
 BUSY_LINES = ("XLA Modules", "XLA Ops", "Async XLA Ops")
 TOP = 10
@@ -127,7 +133,7 @@ def device_ops(events: list[dict]) -> list[list]:
 
 def idle_gaps(events: list[dict]) -> list[list]:
     """The longest idle gaps of the first chip, each named by the innermost
-    harness span open at its middle ("no span open" where none is)."""
+    of `SPANS` open at its middle ("no span open" where none is)."""
     lo, hi = window(events)
     planes = device_planes(events)
     busy = busy_intervals(events, planes[0], lo, hi) if planes else []

@@ -1,5 +1,5 @@
-"""Mean bytes per answer of the arrays `_merge_raw` allocated
-(`QueryPlan.merge_bytes`)."""
+"""Mean bytes per answer of the arrays `_merge_raw` allocated folding chunk
+reads into the answer's array (`QueryPlan.merge_bytes`)."""
 
 
 def read(record):

@@ -1,6 +1,6 @@
-"""Host seconds per answer in ``planner.merge``: `_merge_raw` folding each
-chunk read into the answer's accumulated (partitions, groups, channels)
-array, and the row map's update."""
+"""Host seconds per answer in ``planner.merge``: `_merge_raw` folding chunk
+reads into the answer's accumulated (partitions, groups, channels) array,
+and the row map's update."""
 from perfbench.harness import spans
 
 

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 import repro.api as api
+import repro.planner.planner as planner_mod
 from perfbench.harness import cell as cellmod
 from perfbench.harness import serve, spans, trace
 from repro.backends import ExecOptions
@@ -44,7 +45,8 @@ def reader(name):
 @pytest.fixture(scope="module")
 def served():
     """Four answers through a front door, traced, with every chunk read the
-    planner made recorded beside its answer."""
+    planner made recorded beside its answer, and the bytes of every
+    ``(keys, raw)`` that `_merge_raw` returned, one entry per call."""
     table = make_dataset("tpch", num_partitions=48, rows_per_partition=64)
     sess = api.Session(table, options=JITTED)
     sess.prepare(WorkloadSpec(table, seed=1), num_train_queries=8,
@@ -59,17 +61,27 @@ def served():
         return ans
 
     sess.answers.get_subset = recorded
+    merges = []
+    merge_raw = planner_mod._merge_raw
+
+    def recorded_merge(*args):
+        keys, raw = merge_raw(*args)
+        merges.append(keys.nbytes + raw.nbytes)
+        return keys, raw
+
     queries = [q for q in WorkloadSpec(table, seed=7).sample_workload(12) if q.groupby][:4]
-    door = FrontDoor(sess).start()
     events = []
-    try:
-        with trace.capture(events):
-            with jax.profiler.TraceAnnotation(trace.WINDOW_SPAN):
-                answers = [door.submit(api.QuerySpec(q, error_bound=0.05)).result(timeout=600)
-                           for q in queries]
-    finally:
-        door.stop()
-    return sess, answers, reads, events
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(planner_mod, "_merge_raw", recorded_merge)
+        door = FrontDoor(sess).start()
+        try:
+            with trace.capture(events):
+                with jax.profiler.TraceAnnotation(trace.WINDOW_SPAN):
+                    answers = [door.submit(api.QuerySpec(q, error_bound=0.05)).result(timeout=600)
+                               for q in queries]
+        finally:
+            door.stop()
+    return sess, answers, reads, merges, events
 
 
 def _parents(events):
@@ -87,7 +99,7 @@ def _parents(events):
 
 
 def test_every_span_sits_under_its_parent_on_one_line(served):
-    sess, answers, _, events = served
+    sess, answers, _, merge_calls, events = served
     prog = [e for e in events if e["name"] in spans.PROGRAM]
     assert len({(e["plane"], e["line"]) for e in prog}) == 1
     pairs = _parents(events)
@@ -96,32 +108,41 @@ def test_every_span_sits_under_its_parent_on_one_line(served):
         assert parent in PARENTS[name], (name, parent)
     flushes = sum(1 for e in prog if e["name"] == "door.flush")
     assert flushes == len(answers)
+    # one span per `_merge_raw` call, however many chunk reads each folds
     merges = sum(1 for e in prog if e["name"] == "planner.merge")
-    assert merges == sum(a.plan.chunk_reads for a in answers) == sess.stats()["chunk_evals"]
+    chunk_reads = sum(a.plan.chunk_reads for a in answers)
+    assert merges == len(merge_calls)
+    assert len(answers) <= merges <= chunk_reads == sess.stats()["chunk_evals"]
 
 
 def test_merge_bytes_are_the_shapes_merge_allocates(served):
-    """Per answer, each chunk read grows the merged array to (rows read so
-    far, groups seen so far, channels) float64 beside the int64 group keys."""
-    _, answers, reads, _ = served
+    """The counter adds up the arrays `_merge_raw` returned, each once,
+    whatever the schedule of merges.  Per answer that lies between its
+    final merged array alone, (partitions read, groups seen, channels)
+    float64 beside the int64 group keys, and a new such array at every
+    chunk read."""
+    sess, answers, reads, merges, _ = served
+    assert sum(a.plan.merge_bytes for a in answers) == sum(merges) == sess.stats()["merge_bytes"]
     k = 0
     for ans in answers:
         text = ans.query.describe()
-        rows, keys, expect = 0, np.empty(0, np.int64), 0
+        rows, keys, channels, per_read = 0, np.empty(0, np.int64), 0, 0
         for _ in range(ans.plan.chunk_reads):
             q, ids, group_keys, shape = reads[k]
             k += 1
             assert q == text
             rows += np.unique(ids).size  # a short chunk pads with its first id
             keys = np.union1d(keys, group_keys)
-            expect += 8 * keys.size + 8 * rows * keys.size * shape[2]
-        assert ans.plan.merge_bytes == expect > 0
+            channels = shape[2]
+            per_read += 8 * keys.size + 8 * rows * keys.size * channels
+        final = 8 * keys.size + 8 * rows * keys.size * channels
+        assert 0 < final <= ans.plan.merge_bytes <= per_read
         assert rows == ans.partitions_read
     assert k == len(reads)
 
 
 def test_upload_bytes_per_answer_add_up_to_the_session_total(served):
-    sess, answers, reads, _ = served
+    sess, answers, reads, _, _ = served
     total = sess.stats()["upload_bytes"]
     assert sum(a.plan.upload_bytes for a in answers) == total
     # every chunk read missed and uploaded a fresh (columns + 1, 16, rows)
@@ -132,7 +153,7 @@ def test_upload_bytes_per_answer_add_up_to_the_session_total(served):
 
 
 def test_the_traced_run_feeds_the_new_readers(served):
-    _, answers, _, events = served
+    _, answers, _, _, events = served
     rec = serve.Record(seconds=1.0, start=0.0, events=events)
     rec.requests = [serve.Request(i, {}, "", 0.0, 0.1, a) for i, a in enumerate(answers)]
     got = {name: reader(name).read(rec) for name in METRICS}
@@ -204,6 +225,32 @@ def test_idle_time_goes_to_the_flush_threads_innermost_span_instant_by_instant()
     assert reader("device.idle_in_read_share").read(rec) == pytest.approx(100 * 120 / 795)
     # without the chip's trace there is nothing to share out
     assert spans.idle_shares([e for e in EVENTS if e["plane"] != "/device:TPU:0"]) is None
+
+
+# one idle gap of the chip, [400, 600), and the host spans around it
+GAP_CASES = {
+    "planner.merge": [ev("client.wait", 50, 950), ev("serve.execute", 100, 900),
+                      ev("door.flush", 90, 910), ev("planner.read", 300, 700),
+                      ev("planner.merge", 450, 550)],
+    # JAX's own events, shorter still, are not among the names
+    "read.gather": [ev("serve.execute", 100, 900), ev("planner.read", 300, 700),
+                    ev("serve.chunk_read", 350, 650), ev("read.gather", 400, 600),
+                    ev("np.asarray(jax.Array)", 480, 520)],
+    # program spans before and after the gap, none open at its middle
+    "serve.execute": [ev("client.wait", 50, 950), ev("serve.execute", 100, 900),
+                      ev("planner.select", 100, 450), ev("planner.estimate", 550, 900)],
+    # the window's own span, open across every gap of a real trace, kept off
+    # the host's lines: nothing is open
+    "no span open": [ev("planner.merge", 100, 300)],
+}
+
+
+@pytest.mark.parametrize("name", GAP_CASES)
+def test_an_idle_gap_is_named_by_the_innermost_span_open_at_its_middle(name):
+    window = ev(trace.WINDOW_SPAN, 0, 1000,
+                plane="/host:CPU" if name != "no span open" else "/device:TPU:0")
+    events = [window, busy(0, 400), busy(600, 1000)] + GAP_CASES[name]
+    assert trace.idle_gaps(events) == [[name, pytest.approx(200e-9)]]
 
 
 def test_new_readers_leave_out_what_the_parent_program_lacks():
