@@ -332,6 +332,7 @@ class Session:
             "view_full_rebuilds": self.views.full_rebuilds,
             "chunk_evals": 0 if planner is None else planner.chunk_evals,
             "merge_bytes": 0 if planner is None else planner.merge_bytes,
+            "merges": 0 if planner is None else planner.merges,
             "upload_bytes": self.answers.upload_bytes,
             "read_rate_ema": self._rates.get(self._rate_key()),
             "read_rate_emas": dict(self._rates),

@@ -96,10 +96,12 @@ class QueryPlan:
     # answer is the best estimate produced before it expired)
     deadline_hit: bool = False
     # read cost: chunk reads issued, bytes of the arrays `_merge_raw`
-    # allocated, bytes the chunk reads sent host to device
+    # allocated, bytes the chunk reads sent host to device, `_merge_raw`
+    # calls (one per `_read` call that got a chunk back)
     chunk_reads: int = 0
     merge_bytes: int = 0
     upload_bytes: int = 0
+    merges: int = 0
 
 
 @dataclasses.dataclass
@@ -121,17 +123,22 @@ class _ReadCost:
     chunk_reads: int = 0
     merge_bytes: int = 0
     upload_bytes: int = 0
+    merges: int = 0
 
 
-def _merge_raw(keys_a, raw_a, keys_b, raw_b):
-    """Union the occupied groups of two row-disjoint raw tensors.  Rows
-    are always preserved (a chunk seeing zero groups still read rows)."""
-    keys = np.union1d(keys_a, keys_b)
-    raw = np.zeros((raw_a.shape[0] + raw_b.shape[0], keys.shape[0], raw_b.shape[2]))
-    if keys_a.size:
-        raw[: raw_a.shape[0], np.searchsorted(keys, keys_a)] = raw_a
-    if keys_b.size:
-        raw[raw_a.shape[0]:, np.searchsorted(keys, keys_b)] = raw_b
+def _merge_raw(*pairs):
+    """Union the occupied groups of row-disjoint raw tensors, passed as
+    ``keys_a, raw_a, keys_b, raw_b, ...``: one key union, one new array,
+    each tensor's rows in argument order.  Rows are always preserved (a
+    chunk seeing zero groups still read rows)."""
+    key_sets, raws = pairs[0::2], pairs[1::2]
+    keys = np.unique(np.concatenate(key_sets))
+    raw = np.zeros((sum(r.shape[0] for r in raws), keys.shape[0], raws[-1].shape[2]))
+    lo = 0
+    for k, r in zip(key_sets, raws):
+        if k.size:
+            raw[lo:lo + r.shape[0], np.searchsorted(keys, k)] = r
+        lo += r.shape[0]
     return keys, raw
 
 
@@ -153,6 +160,7 @@ class QueryPlanner:
         self.config = config or PlannerConfig()
         self.chunk_evals = 0  # telemetry: chunk reads issued
         self.merge_bytes = 0  # telemetry: bytes `_merge_raw` allocated
+        self.merges = 0  # telemetry: `_merge_raw` calls
         # fault-aware reads: the injector (None when ExecOptions.faults is
         # unset) gates every chunk read; irrecoverable partitions are
         # masked inside the padded chunk shapes and the answer degrades —
@@ -162,7 +170,9 @@ class QueryPlanner:
     # ---- read path --------------------------------------------------------
     def _read(self, query, new_ids, state, failed: set, cost: _ReadCost):
         """Evaluate `new_ids` in fixed-`chunk`-size subset views and fold
-        them into the accumulated (keys, raw, row_of) state.  Chunks are
+        them into the accumulated (keys, raw, row_of) state with one
+        `_merge_raw` call after the last chunk, so the call allocates the
+        answer's array once however many chunks it reads.  Chunks are
         padded by repeating the first id, so every chunk ships exactly
         ``config.chunk`` partitions — one shape bucket, a flat compile
         census no matter the round or budget.
@@ -172,13 +182,17 @@ class QueryPlanner:
         partitions that exhaust their retries land in ``failed`` and are
         masked *inside* the same padded chunk shape — the survivors pad
         to exactly ``config.chunk`` as before, so failures never mint a
-        new shape bucket or re-trace (the compile census stays flat).
+        new shape bucket or re-trace (the compile census stays flat).  A
+        call whose every chunk died merges nothing.
 
-        ``cost`` adds up the reads, merge allocations and uploads; the
-        uploads are the store's count before and after each read, exact
-        while one thread drives the store (the front door's flush loop)."""
+        ``cost`` adds up the reads, merges, merge allocations and uploads;
+        the uploads are the store's count before and after each read,
+        exact while one thread drives the store (the front door's flush
+        loop)."""
         chunk = self.config.chunk
         keys, raw, row_of = state
+        blocks: list[np.ndarray] = []  # (group_keys, raw) of each chunk read
+        read_ids: list[np.ndarray] = []
         with TraceAnnotation("planner.read"):
             for lo in range(0, len(new_ids), chunk):
                 ids = np.asarray(new_ids[lo:lo + chunk], dtype=np.int64)
@@ -195,11 +209,16 @@ class QueryPlanner:
                 cost.upload_bytes += self.answers.upload_bytes - uploaded
                 self.chunk_evals += 1
                 cost.chunk_reads += 1
+                blocks += [ans.group_keys, ans.raw[:n_real]]
+                read_ids.append(ids[:n_real])
+            if blocks:
                 with TraceAnnotation("planner.merge"):
-                    keys, raw = _merge_raw(keys, raw, ans.group_keys, ans.raw[:n_real])
+                    keys, raw = _merge_raw(keys, raw, *blocks)
+                    cost.merges += 1
+                    self.merges += 1
                     cost.merge_bytes += keys.nbytes + raw.nbytes
                     self.merge_bytes += keys.nbytes + raw.nbytes
-                    for i in ids[:n_real]:
+                    for i in np.concatenate(read_ids):
                         row_of[int(i)] = len(row_of)
         return keys, raw, row_of
 
@@ -472,6 +491,7 @@ class QueryPlanner:
             chunk_reads=cost.chunk_reads,
             merge_bytes=cost.merge_bytes,
             upload_bytes=cost.upload_bytes,
+            merges=cost.merges,
         )
         return PlannedAnswer(
             query, est.group_keys if mode != "hybrid" else self._cap_keys(est, caps),

@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 import repro.api as api
+import repro.planner.planner as planner_mod
 from repro.backends import ExecOptions
 from repro.core import ingest
 from repro.core.features import FeatureBuilder
@@ -34,7 +35,7 @@ from repro.core.sketches import SketchStore, build_sketches, update_sketches
 from repro.data.datasets import make_dataset
 from repro.data.table import Table, append_partitions
 from repro.planner import QueryPlanner, ViewStore
-from repro.planner.planner import _merge_raw
+from repro.planner.planner import PlannerConfig, _merge_raw
 from repro.queries import device
 from repro.queries.engine import (
     AnswerStore,
@@ -201,6 +202,105 @@ def test_merge_raw_keeps_rows_of_groupless_chunks():
     assert np.all(raw[:3] == 0) and np.all(raw[3:] == 1)
     keys2, raw2 = _merge_raw(keys, raw, np.empty(0, np.int64), np.zeros((1, 0, 2)))
     assert raw2.shape == (6, 2, 2) and np.array_equal(keys2, keys)
+
+
+def _pairwise_merge(keys_a, raw_a, keys_b, raw_b):
+    """The fold one chunk at a time: a new union and array per pair."""
+    keys = np.union1d(keys_a, keys_b)
+    raw = np.zeros((raw_a.shape[0] + raw_b.shape[0], keys.shape[0], raw_b.shape[2]))
+    if keys_a.size:
+        raw[: raw_a.shape[0], np.searchsorted(keys, keys_a)] = raw_a
+    if keys_b.size:
+        raw[raw_a.shape[0]:, np.searchsorted(keys, keys_b)] = raw_b
+    return keys, raw
+
+
+def _left_fold(merge, keys, raw, *blocks):
+    for k, r in zip(blocks[0::2], blocks[1::2]):
+        keys, raw = merge(keys, raw, k, r)
+    return keys, raw
+
+
+def _chunk_blocks(rng, n, channels):
+    """``n`` chunk reads' (group keys, raw) as `get_subset` returns them:
+    in turn groupless, disjoint from every other block's codes, and two
+    drawn from one shared range of codes; float32 and float64 raw."""
+    blocks = []
+    for b in range(n):
+        rows = int(rng.integers(1, 17))
+        if b % 4 == 0:
+            keys = np.empty(0, np.int64)
+        elif b % 4 == 1:
+            keys = np.unique(rng.integers(1000 * b, 1000 * b + 50, size=20))
+        else:
+            keys = np.unique(rng.integers(0, 64, size=int(rng.integers(1, 40))))
+        raw = rng.standard_normal((rows, keys.size, channels))
+        blocks += [keys.astype(np.int64), raw.astype(np.float32) if b % 2 else raw]
+    return blocks
+
+
+@pytest.mark.parametrize("start", ["empty", "nonempty"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_merge_raw_in_one_call_equals_the_pairwise_left_fold(seed, start):
+    """One `_merge_raw` call over a state and many chunk blocks gives, bit
+    for bit, the keys and raw of folding the blocks in one at a time."""
+    rng = np.random.default_rng(seed)
+    channels = 3
+    state = (np.empty(0, np.int64), np.zeros((0, 0, channels)))
+    if start == "nonempty":
+        state = _left_fold(_pairwise_merge, *state, *_chunk_blocks(rng, 3, channels))
+    blocks = _chunk_blocks(rng, 12, channels)
+    keys, raw = _merge_raw(*state, *blocks)
+    for fold in (_pairwise_merge, _merge_raw):
+        want_keys, want_raw = _left_fold(fold, *state, *blocks)
+        assert keys.dtype == want_keys.dtype and np.array_equal(keys, want_keys)
+        assert raw.dtype == want_raw.dtype and raw.shape == want_raw.shape
+        assert raw.tobytes() == want_raw.tobytes()
+    rows = state[1].shape[0] + sum(r.shape[0] for r in blocks[1::2])
+    assert raw.shape[0] == rows  # groupless blocks kept their rows
+
+
+def test_one_merge_per_read_answers_as_the_pairwise_fold(ctx, monkeypatch):
+    """Error-bounded answers over several rounds are bit-identical whether
+    each `_read` call folds its chunks in one `_merge_raw` call or one at
+    a time, and ``plan.merges`` counts `_read` calls, not chunk reads."""
+    cfg = PlannerConfig(chunk=4)  # several chunks per `_read` call
+    cases = [(q, bound) for bound in (0.02, 0.1)
+             for q in [q for q in ctx.queries if q.groupby][:3]]
+    sess = api.Session(ctx.table, options=HOST, planner_config=cfg)
+    sess.planner = QueryPlanner(ctx.art.picker, sess.answers, views=sess.views,
+                                config=cfg)
+    read_calls = []
+    read = QueryPlanner._read
+
+    def counted(self, *args):
+        read_calls[-1] += 1
+        return read(self, *args)
+
+    with monkeypatch.context() as mp:
+        mp.setattr(QueryPlanner, "_read", counted)
+        got = []
+        for q, bound in cases:
+            read_calls.append(0)
+            got.append(sess.planner.answer(q, error_bound=bound))
+    monkeypatch.setattr(planner_mod, "_merge_raw",
+                        lambda *pairs: _left_fold(_pairwise_merge, *pairs))
+    planner = QueryPlanner(ctx.art.picker, AnswerStore(ctx.table, options=HOST),
+                           config=cfg)
+    for a, (q, bound), n_reads in zip(got, cases, read_calls):
+        b = planner.answer(q, error_bound=bound)
+        np.testing.assert_array_equal(a.group_keys, b.group_keys)
+        np.testing.assert_array_equal(a.estimate, b.estimate)
+        np.testing.assert_array_equal(a.ci_halfwidth, b.ci_halfwidth)
+        assert a.partitions_read == b.partitions_read
+        assert a.plan.schedule == b.plan.schedule
+        assert a.plan.merges == n_reads
+    assert min(a.plan.rounds for a in got) > 1
+    assert {a.plan.mode for a in got} == {"exact", "sampled"}
+    assert sum(a.plan.merges for a in got) < sum(a.plan.chunk_reads for a in got)
+    stats = sess.stats()
+    assert stats["merges"] == sum(a.plan.merges for a in got)
+    assert stats["chunk_evals"] == sum(a.plan.chunk_reads for a in got)
 
 
 def test_census_flat_across_escalation(ctx):
